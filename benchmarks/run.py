@@ -35,6 +35,9 @@ def main() -> None:
                     help="also write rows to a machine-readable JSON file")
     args = ap.parse_args()
 
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
     from . import bench_tables as B
 
     # (title, fn, timed): timed sections are skipped under --quick.

@@ -40,7 +40,7 @@ def main():
     # ring all-reduce with posit16 payloads on a virtual 1-axis mesh
     mesh = jax.make_mesh((1,), ("pod",))
     x = jnp.asarray(rng.normal(0, 1, 1024).astype(np.float32))
-    from jax.experimental.shard_map import shard_map  # jax.shard_map is 0.5+
+    from jax import shard_map
 
     out = shard_map(
         lambda v: posit_ring_all_reduce(v, "pod", PositFormat(16)),

@@ -231,7 +231,7 @@ class _KernelSourceVisitor(ast.NodeVisitor):
                 self.violations.append(Violation(
                     "pallas-call-discipline", where,
                     "pallas_call without compiler_params: every kernel "
-                    "launch must bound VMEM via TPUCompilerParams("
+                    "launch must bound VMEM via CompilerParams("
                     "vmem_limit_bytes=...)"))
             encl = self.stack[-1] if self.stack else None
             if encl is None or "vmem_limit_bytes" not in _fn_arg_names(encl):

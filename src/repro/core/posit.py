@@ -86,6 +86,10 @@ def _pow2_f32(e):
 def ldexp_f32(m, e):
     """``m * 2^e`` in float32 via two exact power-of-two factors.
 
+    ``m`` is float32 or a signed integer below 2^24 (converted exactly);
+    Mosaic has no unsigned->float cast, so callers holding uint32 values
+    convert through int32.
+
     ``jnp.ldexp`` materializes 2^e as a single f32 factor, which is
     SUBNORMAL for e < -126 and gets flushed to zero on FTZ backends (XLA
     CPU) — so e.g. posit32 minpos-region values (true magnitude ~1e-36,
@@ -97,6 +101,11 @@ def ldexp_f32(m, e):
     e = jnp.clip(e.astype(_I32), -252, 254)
     e1 = e >> 1           # arithmetic shift == floor(e / 2)
     return m.astype(jnp.float32) * _pow2_f32(e1) * _pow2_f32(e - e1)
+
+
+def _bool_where(c, a, b):
+    """``jnp.where`` on bool operands as logic: Mosaic cannot select i1."""
+    return (c & a) | (~c & b)
 
 
 def _safe_shl(x, s):
@@ -144,16 +153,17 @@ def posit_decode(fmt: PositFormat, p) -> PositFields:
     is_zero = p == 0
     is_nar = p == _U32(fmt.nar_pattern)
 
-    sign = ((p >> (n - 1)) & 1).astype(jnp.bool_)
+    # int compares, not bool casts: Mosaic cannot truncate to i1
+    sign = ((p >> (n - 1)) & 1) == 1
     mag = jnp.where(sign, (~p + 1) & _U32(fmt.mask), p)
 
     # Left-align the (n-1)-bit body at bit 31.
     body = (mag << (32 - (n - 1))) & _U32(0xFFFFFFFF)
     r0 = (body >> 31) & 1
-    inv = jnp.where(r0.astype(jnp.bool_), ~body, body) & _U32(0xFFFFFFFF)
+    inv = jnp.where(r0 == 1, ~body, body) & _U32(0xFFFFFFFF)
     run = jax.lax.clz(inv.astype(_I32)).astype(_I32)
     run = jnp.minimum(run, _I32(n - 1))  # regime may run to the end (no terminator)
-    k = jnp.where(r0.astype(jnp.bool_), run - 1, -run)
+    k = jnp.where(r0 == 1, run - 1, -run)
 
     # Bits past regime + terminator.
     tail = _safe_shl(body, (run + 1).astype(_U32))
@@ -192,7 +202,8 @@ def posit_encode(
     scale = scale.astype(_I32)
     frac = frac.astype(_U32)
     round_bit = round_bit.astype(_U32) & 1
-    sticky = sticky.astype(jnp.bool_)
+    if sticky.dtype != jnp.bool_:
+        sticky = sticky != 0
 
     k = scale >> es
     e = (scale & ((1 << es) - 1)).astype(_U32) if es > 0 else jnp.zeros_like(frac)
@@ -221,7 +232,8 @@ def posit_encode(
     guard = jnp.where(discard > 0, g_from_eg, round_bit)
     below_mask = _safe_shl(jnp.full_like(frac, 1), jnp.maximum(discard - 1, 0).astype(_U32)) - 1
     st_eg = (eg & below_mask) != 0
-    sticky_full = jnp.where(discard > 0, st_eg | (round_bit != 0) | sticky, sticky)
+    sticky_full = _bool_where(discard > 0, st_eg | (round_bit != 0) | sticky,
+                              sticky)
 
     # When m == -1 the regime itself is truncated: body = rpat >> 1; the value
     # is then >= the posit's scale ceiling and never rounds up (see below).
@@ -251,7 +263,7 @@ def posit_encode(
         e_disc1 = (e & 1) == 1
         e_disc2 = (e & 3) == 3
         thr = jnp.where(c == 1, _U32(1 << F), _U32(1 << (F - 2)))
-        e_cond = jnp.where(c == 1, e_disc1, e_disc2)
+        e_cond = _bool_where(c == 1, e_disc1, e_disc2)
         deep_up = e_cond & ((f_ext > thr) | ((f_ext == thr) & (lsb == 1)))
         deep = (c >= 1) & (m >= 0)
         inc = jnp.where(deep, deep_up.astype(_U32), inc_linear)
@@ -262,7 +274,9 @@ def posit_encode(
 
     body = jnp.where(over, _U32(fmt.maxpos_body), body)
     body = jnp.where(under, _U32(1), body)
-    body = jnp.clip(body, _U32(1), _U32(fmt.maxpos_body))
+    # compare + select, not clip: Mosaic has no unsigned min/max
+    body = jnp.where(body < 1, _U32(1), body)
+    body = jnp.where(body > _U32(fmt.maxpos_body), _U32(fmt.maxpos_body), body)
 
     p = jnp.where(sign, (~body + 1) & _U32(fmt.mask), body)
     p = jnp.where(is_zero, _U32(0), p)
@@ -278,7 +292,9 @@ def posit_encode(
 def posit_to_float(fmt: PositFormat, p):
     """Posit bits -> float32. Exact for n <= 16; Posit32 rounds to f32."""
     d = posit_decode(fmt, p)
-    sigf = ldexp_f32(d.sig.astype(jnp.float32), d.scale - fmt.F)
+    # sig < 2^(F+1) <= 2^28: int32 holds it, and int32 -> f32 rounds the
+    # same as the unsigned conversion would
+    sigf = ldexp_f32(d.sig.astype(_I32).astype(jnp.float32), d.scale - fmt.F)
     val = jnp.where(d.sign, -sigf, sigf)
     val = jnp.where(d.is_zero, 0.0, val)
     val = jnp.where(d.is_nar, jnp.nan, val)

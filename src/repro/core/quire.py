@@ -139,6 +139,21 @@ def fixed_order_rowsum(x, axis: int = -1, keepdims: bool = True):
     return jnp.expand_dims(acc, ax) if keepdims else acc
 
 
+def fixed_order_lanesum(x, n: int):
+    """:func:`fixed_order_rowsum` of the first ``n`` lanes of a 2-D tile.
+
+    Kernel form of the same left-to-right accumulation: a static unrolled
+    loop over lane slices, because Mosaic lowers no ``dynamic_slice`` of a
+    value.  Lanes past ``n`` are not read; for zero padding that is the
+    same sum, since zeros are additive identities at every partial sum.
+    Returns ``(rows, 1)``.
+    """
+    acc = jnp.zeros((x.shape[0], 1), x.dtype)
+    for j in range(n):
+        acc = acc + x[:, j:j + 1]
+    return acc
+
+
 def fused_dot(fmt: PositFormat, pa, pb, axis: int = -1):
     """Exact posit dot product along ``axis`` with a single final rounding."""
     pa = jnp.moveaxis(pa.astype(_U32), axis, 0)

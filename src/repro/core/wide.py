@@ -73,11 +73,11 @@ def decode_wide(fmt: PositFormat, p: BitVec):
     nar = bv_const(1 << (n - 1), n, bv_to_u32(p))
     is_nar = bv_eq(p, nar)
 
-    sign = bv_bit_dyn(p, jnp.int32(n - 1)).astype(jnp.bool_)
+    sign = bv_bit_dyn(p, jnp.int32(n - 1)) == 1
     mag = bv_select(sign, bv_neg(p), p)
 
     body = bv_shl(mag, 1)  # n-1 bits left-aligned at bit n-1
-    r0 = bv_bit_dyn(body, jnp.int32(n - 1)).astype(jnp.bool_)
+    r0 = bv_bit_dyn(body, jnp.int32(n - 1)) == 1
     inv = bv_select(r0, bv_mask(BitVec([~l for l in body.limbs], n)), body)
     # leading-run length over bits n-1 .. 1
     run = jnp.minimum(_clz(inv), _I32(n - 1))
@@ -102,7 +102,8 @@ def encode_wide(fmt: PositFormat, sign, scale, frac: BitVec, round_bit, sticky,
     like = bv_to_u32(frac)
     scale = scale.astype(_I32)
     round_bit = round_bit.astype(_U32) & 1
-    sticky = sticky.astype(jnp.bool_)
+    if sticky.dtype != jnp.bool_:
+        sticky = sticky != 0
 
     k = scale >> es
     e = (scale & ((1 << es) - 1)).astype(_U32)
@@ -215,9 +216,9 @@ def posit_wide_to_float(fmt: PositFormat, p: BitVec):
                                    bv_to_u32(sig)))
         sticky = (~bv_is_zero(low)).astype(_U32)
         m24 = m24 + (guard & (sticky | (m24 & 1)))
-        val = ldexp_f32(m24, scale - 23)
+        val = ldexp_f32(m24.astype(_I32), scale - 23)
     else:
-        val = ldexp_f32(bv_to_u32(sig), scale - F)
+        val = ldexp_f32(bv_to_u32(sig).astype(_I32), scale - F)
     val = jnp.where(sign, -val, val)
     val = jnp.where(is_zero, 0.0, val)
     return jnp.where(is_nar, jnp.nan, val)

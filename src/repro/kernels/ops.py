@@ -37,7 +37,8 @@ not fit a uint32 word); the float-in/float-out fused entry points accept
 every planned format including posit64.
 
 The softmax kernel's f32 row SUM runs in FIXED left-to-right order
-(:func:`repro.core.quire.fixed_order_rowsum`), as does the emulate path's:
+(:func:`repro.core.quire.fixed_order_lanesum`, the unrolled kernel form of
+the emulate path's :func:`repro.core.quire.fixed_order_rowsum`):
 appended pad zeros are additive identities at every partial sum, so the
 padded in-kernel reduction is bit-identical to the unpadded emulate one —
 for every format including posit64, which keeps all f32 mantissa bits and
@@ -65,6 +66,10 @@ FUSED_DIV_VARIANTS = _div.KERNEL_VARIANTS
 
 _DEFAULT_BLOCK = (64, 256)
 _ROW_BLOCK = 64    # preferred row tile for the rowwise/softmax kernels
+# Largest tile (elements) a divider kernel body takes: the datapath keeps
+# ~100 block-sized int32 temporaries live, and at 64 x 256 that fits the
+# VMEM budget (posit_div.VMEM_LIMIT_BYTES).  Wide rows get fewer rows.
+_TILE_ELEMS = 64 * 256
 _LANE = 128        # TPU lane width: last-dim padding multiple
 
 
@@ -106,9 +111,11 @@ def _tile_2d(x, block, pad_value=0):
     return flat.reshape(rows_pad, cols), total
 
 
-def _row_block(R: int) -> int:
-    """Row-tile height: sublane-aligned, capped by the (padded) row count."""
-    return min(_ROW_BLOCK, _round_up(R, 8))
+def _row_block(R: int, width: int) -> int:
+    """Row-tile height: sublane-aligned, capped by the (padded) row count
+    and by the tile budget at this tile width."""
+    fit = max(8, _TILE_ELEMS // width // 8 * 8)
+    return min(_ROW_BLOCK, _round_up(R, 8), fit)
 
 
 def _row_tile(a2, b2):
@@ -119,10 +126,10 @@ def _row_tile(a2, b2):
     block shape, and the original (R, C).
     """
     R, C = a2.shape
-    bm = _row_block(R)
-    Rp = _round_up(R, bm)
     Cp = _round_up(C, _LANE)
     bn = max(b for b in (512, 256, _LANE) if Cp % b == 0)
+    bm = _row_block(R, bn)
+    Rp = _round_up(R, bm)
     a2 = jnp.pad(a2, ((0, Rp - R), (0, Cp - C)))
     b2 = jnp.pad(b2, ((0, Rp - R), (0, 0)), constant_values=1.0)
     return a2, b2, (bm, bn), (R, C)
@@ -233,9 +240,9 @@ def posit_softmax_fused(fmt: PositFormat, x, interpret=None,
     C = shape[-1]
     x2 = x.astype(jnp.float32).reshape(-1, C)
     R = x2.shape[0]
-    bm = _row_block(R)
-    Rp = _round_up(R, bm)
     Cp = _round_up(C, _LANE)
+    bm = _row_block(R, Cp)
+    Rp = _round_up(R, bm)
     x2 = jnp.pad(x2, ((0, Rp - R), (0, Cp - C)))
     out = _fused.posit_softmax_fused_pallas(fmt, x2, C, bm,
                                             interpret, variant=variant)

@@ -17,7 +17,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.posit import PositFormat, float_to_posit, posit_to_float
-from .posit_div import resolve_interpret
+from .posit_div import VMEM_LIMIT_BYTES, resolve_interpret
 
 _U32 = jnp.uint32
 
@@ -33,7 +33,7 @@ def _dequant_kernel(p_ref, o_ref, *, fmt: PositFormat):
 @functools.partial(jax.jit, static_argnums=(0, 2, 3, 4))
 def posit_quantize_pallas(fmt: PositFormat, x, block=(64, 256),
                           interpret: Optional[bool] = None,
-                          vmem_limit_bytes: int = 64 * 1024 * 1024):
+                          vmem_limit_bytes: int = VMEM_LIMIT_BYTES):
     assert x.ndim == 2
     interpret = resolve_interpret(interpret)
     bm, bn = block
@@ -46,7 +46,7 @@ def posit_quantize_pallas(fmt: PositFormat, x, block=(64, 256),
         grid=(m // bm, n // bn),
         in_specs=[spec],
         out_specs=spec,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(x.astype(jnp.float32))
@@ -55,7 +55,7 @@ def posit_quantize_pallas(fmt: PositFormat, x, block=(64, 256),
 @functools.partial(jax.jit, static_argnums=(0, 2, 3, 4))
 def posit_dequantize_pallas(fmt: PositFormat, p, block=(64, 256),
                             interpret: Optional[bool] = None,
-                            vmem_limit_bytes: int = 64 * 1024 * 1024):
+                            vmem_limit_bytes: int = VMEM_LIMIT_BYTES):
     assert p.ndim == 2
     interpret = resolve_interpret(interpret)
     bm, bn = block
@@ -68,7 +68,7 @@ def posit_dequantize_pallas(fmt: PositFormat, p, block=(64, 256),
         grid=(m // bm, n // bn),
         in_specs=[spec],
         out_specs=spec,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(p.astype(_U32))

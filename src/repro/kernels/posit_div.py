@@ -71,6 +71,13 @@ _IB = 3         # residual integer bits (incl. sign) at the top of the frame
 _WPOINT = 29    # fraction bits held by the TOP residual word (32 - _IB)
 _MAX_WORDS = 2  # widest residual frame: two words, 61 fraction bits
 
+# Scoped-VMEM budgets the kernels request; v5e has 128 MiB of VMEM per
+# core.  Elementwise tiles need well under a MiB.  The flash kernels hold a
+# whole (Sk, 128) f32 K and V per grid step, double-buffered: 4 MiB at
+# Sk = 2048, so 64 MiB covers contexts to about 16K.
+VMEM_LIMIT_BYTES = 32 * 1024 * 1024
+FLASH_VMEM_LIMIT_BYTES = 64 * 1024 * 1024
+
 # Exported for the static prover (repro.analysis.datapath): the W-word
 # residual frame holds values in [-2^(_IB-1), 2^(_IB-1)) with 32*W - _IB
 # fraction bits; the prover shows every reachable residual/divisor multiple
@@ -532,7 +539,7 @@ def _divide_fields(plan: DatapathPlan, xsig: Tuple, dsig: Tuple):
     rem_nz = _w_nonzero(rem)
 
     # q = qf * 2^-FP in (1/2, 2); normalize and extract F + G/R/S bits.
-    intbit = _w_bit(qf, FP).astype(jnp.bool_)
+    intbit = _w_bit(qf, FP) == 1
     qn = _w_sel(intbit, qf, _w_shl(qf, 1))
     t_adj = jnp.where(intbit, _I32(0), _I32(-1))
     frac = _w_mask(_w_shr(qn, FP - F), F)
@@ -638,7 +645,7 @@ def posit_div_pallas(
     pd,
     block=(64, 256),
     interpret: Optional[bool] = None,
-    vmem_limit_bytes: int = 64 * 1024 * 1024,
+    vmem_limit_bytes: int = VMEM_LIMIT_BYTES,
     variant: str = DEFAULT_KERNEL_VARIANT,
 ):
     """Tiled Pallas divider over a 2D uint32 array (pre-padded by ops.py)."""
@@ -655,7 +662,7 @@ def posit_div_pallas(
         grid=grid,
         in_specs=[spec, spec],
         out_specs=spec,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(px.astype(_U32), pd.astype(_U32))

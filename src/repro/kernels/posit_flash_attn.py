@@ -87,7 +87,8 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.posit import PositFormat
 from .ops import _on_tpu, _round_up
-from .posit_div import DEFAULT_KERNEL_VARIANT, divide_floats_block
+from .posit_div import (DEFAULT_KERNEL_VARIANT, FLASH_VMEM_LIMIT_BYTES,
+                        divide_floats_block)
 
 _NEG_INF = -1e30  # matches the jnp flash path's mask fill
 _RES_LANES = 128  # lane width of the row-residual (m, l) kernel outputs
@@ -115,8 +116,9 @@ def _flash_kernel(*refs,
                   has_seg: bool = False):
     if pages:
         # paged mode: k/v refs are the WHOLE block pools in kernel layout
-        # (num_blocks * KV, block_size, hdp) plus this sequence's block
-        # table row; each kv tile is gathered as ``pages`` pool pages
+        # (num_blocks * KV, block_size, hdp) plus the flattened (B *
+        # bt_cols,) block tables in SMEM; each kv tile is gathered as
+        # ``pages`` pool pages
         q_ref, k_ref, v_ref, bt_ref, ks_ref, kl_ref, qp_ref, *out_refs = refs
     else:
         q_ref, k_ref, v_ref, ks_ref, kl_ref, qp_ref, *out_refs = refs
@@ -124,21 +126,22 @@ def _flash_kernel(*refs,
         # packed prefill: per-position segment ids ride as the last two
         # inputs (lane-broadcast q rows, sublane-broadcast kv row)
         sq_ref, skv_ref, out_refs = out_refs[0], out_refs[1], out_refs[2:]
+    # program_id is read at kernel-body level: the interpreter substitutes
+    # it there, and inside the fori_loop jaxpr it would not lower
+    bh = pl.program_id(0)
+    seq = bh // n_heads                             # batch row of this head
     q = q_ref[0]                                    # (bq, hdp) f32
-    kv_start = ks_ref[0, 0]                         # scalar int32 (pad prefix)
-    kv_len = jnp.minimum(kl_ref[0, 0], sk_valid)    # per-sequence valid rows
+    kv_start = ks_ref[seq]                          # SMEM scalars (B,) int32
+    kv_len = jnp.minimum(kl_ref[seq], sk_valid)     # per-sequence valid rows
     iq = pl.program_id(1)
-    q_pos = qp_ref[0, 0] + q_offset + iq * bq + jax.lax.broadcasted_iota(
+    q_pos = qp_ref[seq] + q_offset + iq * bq + jax.lax.broadcasted_iota(
         jnp.int32, (bq, 1), 0)
     seg_q = sq_ref[0][:, :1] if has_seg else None   # (bq, 1) int32
 
     m0 = jnp.full((bq, 1), _NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((bq, 1), dtype=jnp.float32)
     a0 = jnp.zeros(q.shape, dtype=jnp.float32)
-    # hoisted out of the loop body: program_id is resolved at kernel-body
-    # level (the interpreter substitutes it; inside the fori_loop jaxpr it
-    # would not lower)
-    kvh = (pl.program_id(0) % n_heads) // group if pages else 0
+    kvh = (bh % n_heads) // group if pages else 0
 
     def kv_step(j, carry):
         m, l, acc = carry
@@ -153,12 +156,10 @@ def _flash_kernel(*refs,
             pk, pv = [], []
             for t in range(pages):
                 col = jnp.minimum(j * pages + t, bt_cols - 1)
-                bid = pl.load(bt_ref, (slice(None), pl.ds(col, 1)))[0, 0]
+                bid = bt_ref[seq * bt_cols + col]
                 row = jnp.clip(bid, 0, num_blocks - 1) * kv_heads + kvh
-                pk.append(pl.load(
-                    k_ref, (pl.ds(row, 1), slice(None), slice(None)))[0])
-                pv.append(pl.load(
-                    v_ref, (pl.ds(row, 1), slice(None), slice(None)))[0])
+                pk.append(k_ref[row])
+                pv.append(v_ref[row])
             kj = jnp.concatenate(pk, axis=0) if pages > 1 else pk[0]
             vj = jnp.concatenate(pv, axis=0) if pages > 1 else pv[0]
         else:
@@ -265,18 +266,28 @@ def _flash_call(fmt, q, k, v, causal, window, q_offset, scale, variant,
             "(power of two <= 128)")
         kf = _pool_kernel_layout(k, hdp)
         vf = _pool_kernel_layout(v, hdp)
-        btf = block_tables.astype(jnp.int32)
-        paged_kw = dict(pages=bk // bsz, n_heads=H, kv_heads=KV, group=G,
+        # the kernel holds both whole pools in VMEM, double-buffered; a
+        # pool past the budget is refused here rather than by Mosaic
+        pool_vmem = 4 * kf.size * kf.dtype.itemsize
+        if not interpret and pool_vmem > vmem_limit_bytes:
+            raise ValueError(
+                f"paged fused attention keeps the whole KV block pools in "
+                f"VMEM: {pool_vmem} bytes for {NB} blocks of {bsz} rows x "
+                f"{KV} kv heads, over the {vmem_limit_bytes}-byte budget; "
+                "serve this size with the dense KV layout or "
+                "attn_backend='xla'")
+        btf = block_tables.astype(jnp.int32).reshape(-1)
+        paged_kw = dict(pages=bk // bsz, kv_heads=KV, group=G,
                         num_blocks=NB, bt_cols=block_tables.shape[1])
     else:
         kf = _to_kernel_layout(k, Skp, hdp)
         vf = _to_kernel_layout(v, Skp, hdp)
 
     def _per_seq(vec, default):
-        """(B,) per-sequence int32 -> (B*H, 1) per-grid-row scalar input."""
+        """(B,) per-sequence int32 scalars; the kernel reads them from SMEM."""
         if vec is None:
-            return jnp.full((B * H, 1), default, jnp.int32)
-        return jnp.repeat(vec.astype(jnp.int32), H).reshape(B * H, 1)
+            return jnp.full((B,), default, jnp.int32)
+        return vec.astype(jnp.int32).reshape(B)
 
     ksf = _per_seq(kv_start, 0)
     klf = _per_seq(kv_len, Sk)
@@ -307,7 +318,8 @@ def _flash_call(fmt, q, k, v, causal, window, q_offset, scale, variant,
     kernel = functools.partial(
         _flash_kernel, fmt=fmt, variant=variant, causal=causal,
         window=window, q_offset=q_offset, scale=scale, bq=bq, bk=bk,
-        nk=nk, sk_valid=Sk, save_res=save_res, has_seg=has_seg, **paged_kw)
+        nk=nk, sk_valid=Sk, save_res=save_res, has_seg=has_seg, n_heads=H,
+        **paged_kw)
     out_shape = [jax.ShapeDtypeStruct((B * H, Sqp, hdp), jnp.float32)]
     out_specs = [pl.BlockSpec((1, bq, hdp), lambda b, i: (b, i, 0))]
     if save_res:
@@ -315,13 +327,14 @@ def _flash_call(fmt, q, k, v, causal, window, q_offset, scale, variant,
                                                jnp.float32)]
         out_specs += 2 * [pl.BlockSpec((1, bq, _RES_LANES),
                                        lambda b, i: (b, i, 0))]
+    # per-sequence scalars and block tables: whole arrays in SMEM
+    smem = pl.BlockSpec(memory_space=pltpu.SMEM)
     if paged:
         # the pools ride along whole (constant index map) — pages are
         # gathered in-kernel from the per-sequence block-table row
         kv_specs = [pl.BlockSpec(kf.shape, lambda b, i: (0, 0, 0))] * 2
         inputs = (qf, kf, vf, btf, ksf, klf, qpf) + seg_inputs
-        extra = [pl.BlockSpec((1, block_tables.shape[1]),
-                              lambda b, i: (b // H, 0))]
+        extra = [smem]
     else:
         kv_specs = 2 * [pl.BlockSpec(
             (1, Skp, hdp), lambda b, i: (b // H * KV + (b % H) // G, 0, 0))]
@@ -332,13 +345,9 @@ def _flash_call(fmt, q, k, v, causal, window, q_offset, scale, variant,
         out_shape=out_shape,
         grid=(B * H, Sqp // bq),
         in_specs=[pl.BlockSpec((1, bq, hdp), lambda b, i: (b, i, 0))]
-        + kv_specs + extra + [
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, 0)),
-        ] + seg_specs,
+        + kv_specs + extra + 3 * [smem] + seg_specs,
         out_specs=out_specs,
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes),
         interpret=interpret,
     )(*inputs)
@@ -367,7 +376,7 @@ def posit_flash_attention(
     interpret: bool = None,
     block_q: int = 128,
     block_k: int = 128,
-    vmem_limit_bytes: int = 128 * 1024 * 1024,
+    vmem_limit_bytes: int = FLASH_VMEM_LIMIT_BYTES,
     kv_start=None,
     kv_len=None,
     q_pos=None,
@@ -434,7 +443,7 @@ def posit_flash_attention_fwd(
     interpret: bool = None,
     block_q: int = 128,
     block_k: int = 128,
-    vmem_limit_bytes: int = 128 * 1024 * 1024,
+    vmem_limit_bytes: int = FLASH_VMEM_LIMIT_BYTES,
 ):
     """Forward pass that also returns the recompute-backward residuals.
 
@@ -519,16 +528,12 @@ def _flash_bwd_dkv_kernel(q_ref, g_ref, m_ref, l_ref, d_ref, k_ref, v_ref,
     def q_step(t, carry):
         dk, dv = carry
         g, i = t // nq, t % nq
-        q = pl.load(q_ref, (pl.ds(g, 1), pl.ds(i * bq, bq),
-                            slice(None)))[0]        # (bq, hdp)
-        go = pl.load(g_ref, (pl.ds(g, 1), pl.ds(i * bq, bq),
-                             slice(None)))[0]
-        mrow = pl.load(m_ref, (pl.ds(g, 1), pl.ds(i * bq, bq),
-                               pl.ds(0, 1)))[0]     # (bq, 1)
-        lrow = pl.load(l_ref, (pl.ds(g, 1), pl.ds(i * bq, bq),
-                               pl.ds(0, 1)))[0]
-        drow = pl.load(d_ref, (pl.ds(g, 1), pl.ds(i * bq, bq),
-                               pl.ds(0, 1)))[0]
+        rows = pl.ds(i * bq, bq)
+        q = q_ref[g, rows, :]                       # (bq, hdp)
+        go = g_ref[g, rows, :]
+        mrow = m_ref[g, rows, :][:, :1]             # (bq, 1)
+        lrow = l_ref[g, rows, :][:, :1]
+        drow = d_ref[g, rows, :][:, :1]
         l_safe = jnp.where(lrow > 0, lrow, _minpos_eps(fmt))
         q_pos = q_offset + i * bq + jax.lax.broadcasted_iota(
             jnp.int32, (bq, 1), 0)
@@ -558,7 +563,7 @@ def _flash_backward(fmt: PositFormat, q, k, v, o, g, m, l,
                     causal: bool, window: int, q_offset: int, scale: float,
                     variant: str, interpret: bool = None,
                     block_q: int = 128, block_k: int = 128,
-                    vmem_limit_bytes: int = 128 * 1024 * 1024):
+                    vmem_limit_bytes: int = FLASH_VMEM_LIMIT_BYTES):
     """Recompute-style fused backward: (dq, dk, dv) from the saved row
     residuals, with no (Sq, Sk) intermediate anywhere.
 
@@ -594,7 +599,7 @@ def _flash_backward(fmt: PositFormat, q, k, v, o, g, m, l,
         return jnp.broadcast_to(x[:, :, None], (B * H, Sqp, _RES_LANES))
 
     mb, lb, Db = rows(m), rows(l), rows(D)
-    params = pltpu.TPUCompilerParams(vmem_limit_bytes=vmem_limit_bytes)
+    params = pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
     kv_map = lambda b, i: (b // H * KV + (b % H) // G, 0, 0)  # noqa: E731
     row_spec = pl.BlockSpec((1, bq, _RES_LANES), lambda b, i: (b, i, 0))
 

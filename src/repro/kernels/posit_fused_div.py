@@ -60,8 +60,10 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.posit import PositFormat
+from repro.core.quire import fixed_order_lanesum
 from .posit_div import (
     DEFAULT_KERNEL_VARIANT,
+    VMEM_LIMIT_BYTES,
     divide_floats_block,
     resolve_interpret,
 )
@@ -75,7 +77,7 @@ _NEG_HUGE = -3.4e38
 
 
 def _compiler_params(vmem_limit_bytes: int):
-    return pltpu.TPUCompilerParams(vmem_limit_bytes=vmem_limit_bytes)
+    return pltpu.CompilerParams(vmem_limit_bytes=vmem_limit_bytes)
 
 
 def _fused_kernel(a_ref, b_ref, o_ref, *, fmt: PositFormat, variant: str):
@@ -89,7 +91,7 @@ def posit_fused_div_pallas(
     b,
     block=(64, 256),
     interpret: Optional[bool] = None,
-    vmem_limit_bytes: int = 64 * 1024 * 1024,
+    vmem_limit_bytes: int = VMEM_LIMIT_BYTES,
     variant: str = DEFAULT_KERNEL_VARIANT,
 ):
     """Tiled fused divider over 2D float32 arrays (pre-padded by ops.py)."""
@@ -130,7 +132,7 @@ def posit_fused_div_rowwise_pallas(
     b,
     block=(8, 256),
     interpret: Optional[bool] = None,
-    vmem_limit_bytes: int = 64 * 1024 * 1024,
+    vmem_limit_bytes: int = VMEM_LIMIT_BYTES,
     variant: str = DEFAULT_KERNEL_VARIANT,
 ):
     """Row-broadcast fused divider: ``a[(rows, cols)] / b[(rows, 1)]``.
@@ -178,9 +180,7 @@ def _softmax_kernel(x_ref, o_ref, *, fmt: PositFormat, variant: str,
     # every format (posit64 keeps all f32 mantissa bits — a free-order
     # jnp.sum here cost it 1 ulp of cross-backend agreement).
     e = jnp.where(valid, jnp.exp(x - m), 0.0)
-    from repro.core.quire import fixed_order_rowsum
-
-    s = fixed_order_rowsum(e, axis=-1)                # (bm, 1)
+    s = fixed_order_lanesum(e, cols_valid)            # (bm, 1)
     o_ref[...] = divide_floats_block(fmt, e, s, variant)
 
 
@@ -191,7 +191,7 @@ def posit_softmax_fused_pallas(
     cols_valid: int,
     block_rows: int = 8,
     interpret: Optional[bool] = None,
-    vmem_limit_bytes: int = 64 * 1024 * 1024,
+    vmem_limit_bytes: int = VMEM_LIMIT_BYTES,
     variant: str = DEFAULT_KERNEL_VARIANT,
 ):
     """Single-launch posit softmax over complete rows.

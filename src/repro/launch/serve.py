@@ -28,6 +28,7 @@ import jax
 import numpy as np
 
 from repro.configs import get_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as T
 from repro.serve import (FinishEvent, ReplicaRouter, Request, ServeConfig,
                          ServeEngine, TokenEvent, stream_async)
@@ -110,6 +111,7 @@ def main():
                          "stepping decoupled from print/emit latency); "
                          "implies --stream")
     args = ap.parse_args()
+    enable_compile_cache()
 
     # serving limits ride on the model config (get_config overrides), so no
     # ad hoc ServeConfig mutation here
@@ -137,8 +139,10 @@ def main():
     if args.tp > 1 or args.replicas > 1:
         from repro.launch.mesh import serve_meshes
         meshes = serve_meshes(args.tp, args.replicas)
-        engines = [ServeEngine(cfg, params, sc,
-                               mesh=m if args.tp > 1 else None)
+        # tp == 1: each replica is unsharded and lives on its own device
+        engines = [ServeEngine(cfg, params, sc, mesh=m) if args.tp > 1
+                   else ServeEngine(cfg, params, sc,
+                                    device=m.devices.flat[0])
                    for m in meshes]
         eng = ReplicaRouter(engines) if args.replicas > 1 else engines[0]
         print(f"# topology: tp={args.tp} x replicas={args.replicas} over "

@@ -16,6 +16,7 @@ import jax
 from repro.configs import get_config
 from repro.data import DataConfig, SyntheticLMDataset
 from repro.launch import mesh as M
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import sharding as SH
 from repro.train import CheckpointManager, TrainConfig, Trainer
 from repro.train.trainer import init_train_state, make_train_step
@@ -42,6 +43,7 @@ def main():
     ap.add_argument("--distributed", action="store_true",
                     help="initialize jax.distributed + production mesh")
     args = ap.parse_args()
+    enable_compile_cache()
 
     logging.basicConfig(level=logging.INFO,
                         format="%(asctime)s %(name)s %(message)s")

@@ -164,8 +164,8 @@ def flash_attention(q, k, v, cfg: ModelConfig, *, causal: bool,
             blk = min(128, seg_len)
             out = posit_flash_attention(
                 nm.div_fmt, q, k, v, causal, window, q_offset, 0.0,
-                nm.div_algo, None, blk, blk, 128 * 1024 * 1024,
-                kv_start=kv_start, seg_q=seg_q, seg_kv=seg_kv)
+                nm.div_algo, None, blk, blk, kv_start=kv_start, seg_q=seg_q,
+                seg_kv=seg_kv)
         elif kv_start is not None:
             # ragged serving prefill: forward-only kernel with the pad-
             # prefix mask (the training path never carries kv_start)

@@ -281,7 +281,9 @@ the single-device engine times ``tp``, not a different program.
     probe asserts a post-:meth:`warmup` serve compiles nothing new.
   * **Data parallelism** is replica routing, not batch sharding: a
     :class:`repro.serve.router.ReplicaRouter` owns N independent engines
-    on disjoint device subsets, routes ``submit()`` least-loaded, and
+    on disjoint device subsets (an unsharded replica takes ``device=``
+    instead of a mesh and keeps its params and caches on that device),
+    routes ``submit()`` least-loaded, and
     merges the per-replica streams behind the single-engine surface —
     aggregate throughput scales with replicas while per-request
     semantics (FinishReason, deadlines, quarantine, bit-identity) are
@@ -328,7 +330,7 @@ from typing import Callable, Dict, List, Optional, Sequence, Union
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, PartitionSpec as PSpec
 
 from repro.models import transformer as T
@@ -773,7 +775,11 @@ class ServeEngine:
     def __init__(self, cfg: ModelConfig, params,
                  sc: Optional[ServeConfig] = None,
                  clock: Optional[Callable[[], float]] = None,
-                 mesh: Optional[Mesh] = None):
+                 mesh: Optional[Mesh] = None,
+                 device: Optional[jax.Device] = None):
+        if mesh is not None and device is not None:
+            raise ValueError("pass mesh= (tensor-parallel) or device= "
+                             "(unsharded replica), not both")
         self.cfg = cfg
         self.sc = sc if sc is not None else ServeConfig.from_model(cfg)
         # injectable wall clock (seconds) so deadline tests run
@@ -792,6 +798,7 @@ class ServeEngine:
         # are precomputed HERE, once: the hot loop never reshards (the
         # analysis layout probe asserts this).
         self._mesh = mesh
+        self._device = device
         self._tp = 1
         self._pspec = self._cspec = None
         self._param_sharding = self._cache_sharding = None
@@ -850,6 +857,10 @@ class ServeEngine:
             self._param_sharding = MX.named(mesh, self._pspec)
             self._cache_sharding = MX.named(mesh, self._cspec)
             params = jax.device_put(params, self._param_sharding)
+        elif device is not None:
+            # the jitted executables run where their committed params
+            # live; host inputs are uncommitted and follow them
+            params = jax.device_put(params, device)
         self.params = params
         self._mcfg = mcfg
 
@@ -857,13 +868,13 @@ class ServeEngine:
 
         def sm(fn, in_specs, out_specs):
             # shard_map over the serve mesh; identity when unsharded.
-            # check_rep=False: the decode body's collectives are the
+            # check_vma=False: the decode body's collectives are the
             # fixed-order all-gathers in models/layers, whose replication
-            # the rep checker cannot prove through lax.scan
+            # the checker cannot prove through lax.scan
             if mesh is None:
                 return fn
             return shard_map(fn, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+                             out_specs=out_specs, check_vma=False)
 
         self._sm = sm
         # the persistent cache is donated (argument 1 / 0): it is rebound on
@@ -1077,13 +1088,16 @@ class ServeEngine:
 
     def _place_cache(self, cache):
         """Commit a cache tree (full, mini, pool — same structure) to the
-        engine's precomputed KV sharding; identity on unsharded engines.
+        engine's precomputed KV sharding, or to its ``device``; identity
+        on unplaced engines.
         Every cache template passes through here at CREATION, so the hot
         loop's donated executables see exactly the layout they were
         compiled for and never reshard implicitly."""
-        if self._mesh is None:
-            return cache
-        return jax.device_put(cache, self._cache_sharding)
+        if self._mesh is not None:
+            return jax.device_put(cache, self._cache_sharding)
+        if self._device is not None:
+            return jax.device_put(cache, self._device)
+        return cache
 
     def load(self) -> int:
         """Routing load for the ReplicaRouter: active slots + queued

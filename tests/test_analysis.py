@@ -154,7 +154,7 @@ def test_entry_coverage(entries):
 
 
 def test_f64_leak_flagged():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         e = trace_entry(
             "leaky", lambda x: x.astype(jnp.float64) * 2.0,
             (jax.ShapeDtypeStruct((4,), jnp.float32),), tags=())

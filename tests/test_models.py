@@ -160,3 +160,25 @@ def test_full_configs_param_counts():
                                 jax.random.PRNGKey(0))
         n = sum(math.prod(l.shape) for l in jax.tree_util.tree_leaves(shapes))
         assert lo * 1e9 < n < hi * 1e9, (arch, n / 1e9)
+
+
+@pytest.mark.parametrize("fused", [False, True], ids=["xla", "fused"])
+def test_prefill_matches_f32_reference(fused):
+    """SmolLM-360M heads (15 q / 5 kv) at a cut width: the bfloat16 model's
+    prefill logits, with float or fused posit division, stay within
+    bfloat16 rounding of the plain float32 reference forward."""
+    from repro.models.reference import reference_logits
+
+    cfg = get_config("smollm_360m", smoke=True, fused=fused).replace(
+        n_heads=15, n_kv_heads=5, head_dim=16, d_model=240, d_ff=640)
+    params = T.init_params(cfg, jax.random.PRNGKey(1))
+    toks = jnp.asarray(np.random.default_rng(0).integers(
+        1, cfg.vocab, (1, 40)), jnp.int32)
+    lg, _ = jax.jit(lambda p, c, t: T.prefill(p, cfg, {"tokens": t}, c))(
+        params, T.init_cache(cfg, 1, 64), toks)
+    got = np.asarray(lg[0, -1, :cfg.vocab], np.float32)
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(reference_logits(params, cfg, toks)[0, -1])
+    rel = np.linalg.norm(got - want) / np.linalg.norm(want)
+    assert rel < 0.06, rel
+    assert got.argmax() == want.argmax()

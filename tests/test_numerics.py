@@ -65,7 +65,7 @@ def test_gradient_compression_error_bound():
 def test_posit_ring_all_reduce_single_axis():
     """shard_map ring all-reduce == psum on a 1-device axis (degenerate)."""
     from repro.optim.grad_compress import posit_ring_all_reduce
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = jax.make_mesh((1,), ("pod",))

@@ -180,6 +180,24 @@ def test_router_rejects_bad_args(model):
         ReplicaRouter([eng], policy="random")
 
 
+def test_unsharded_replica_on_its_device(model, single):
+    """``device=`` commits an unsharded replica's params and cache to that
+    device without changing its tokens."""
+    cfg, params = model
+    dev = jax.devices()[-1]
+    eng = ServeEngine(cfg, params, ServeConfig(max_batch=2, max_seq=64),
+                      device=dev)
+    assert {d for a in jax.tree.leaves(eng.params) for d in a.devices()} \
+        == {dev}
+    single.serve(_reqs())
+    eng.serve(_reqs())
+    assert _results(eng) == _results(single)
+    assert {d for a in jax.tree.leaves(eng._st.cache)
+            for d in a.devices()} == {dev}
+    with pytest.raises(ValueError, match="not both"):
+        ServeEngine(cfg, params, mesh=MX.serve_meshes(1, 1)[0], device=dev)
+
+
 def test_router_mid_stream_submit(single, router):
     """Submissions made while consuming the merged stream are routed and
     finish with the same tokens as a single engine serving them all."""
