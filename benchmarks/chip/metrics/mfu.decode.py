@@ -1,0 +1,16 @@
+"""Model step, decode (the engine's decode executable): the FLOPs the
+traced span's decode tokens need (active slots, live KV rows) over the
+decode executions' device time times the chip's bf16 peak, in %."""
+
+import trace_reduce as TRC
+import work
+
+
+def read(rec):
+    st = TRC.record_steps(rec)
+    rows = rec["trace"]["decode_live_rows"] if st else []
+    if not st or not st["decode"] or not rows:
+        return None
+    t = TRC.seconds_of(rec["trace"]["reduced"], st["decode"])
+    return 100.0 * work.decode_flops(rec["model"], rows) / (
+        t * rec["peaks"]["bf16_flops_per_s"])
