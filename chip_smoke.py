@@ -350,10 +350,6 @@ def _both_dividers():
         posit_ops.posit_div_values = layers.posit_div_values = div
 
 
-def _pct(xs, q):
-    return float(np.percentile(np.asarray(xs, np.float64), q))
-
-
 def serve_phase(seed, dev, *, arch="smollm-360m", smoke=False, n_req=16,
                 plen=(16, 512), max_new=32, max_batch=8, max_seq=2048):
     import jax
@@ -385,17 +381,10 @@ def serve_phase(seed, dev, *, arch="smollm-360m", smoke=False, n_req=16,
     log(f"[serve] compile+warmup {time.perf_counter() - t0:.2f}s for "
         f"prompt buckets {buckets}")
 
-    t0 = time.perf_counter()
     results = _stream(eng, reqs)
-    wall = time.perf_counter() - t0
     _check_finished(results, n_req, "fused engine")
-    new_tokens = sum(len(r.tokens) for r in results.values())
-    st = eng.last_serve_stats
-    log(f"[serve] {n_req} requests, {new_tokens} tokens in {wall:.3f}s: "
-        f"{new_tokens / wall:.1f} tokens/s (host clock, warm)")
-    log(f"[serve] ttft p50 {_pct(st['ttft_ms'], 50):.1f} ms, "
-        f"p99 {_pct(st['ttft_ms'], 99):.1f} ms; finish "
-        f"{dict(st['finish_reasons'])}")
+    log(f"[serve] {n_req} requests; finish "
+        f"{dict(eng.last_serve_stats['finish_reasons'])}")
     counts = eng.executable_counts()
     log(f"[serve] executable_counts {counts}"
         + ("" if counts == census else f"; after warmup it was {census}"))

@@ -42,6 +42,7 @@ def posit_quantize_pallas(fmt: PositFormat, x, block=(64, 256),
     spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
     return pl.pallas_call(
         functools.partial(_quant_kernel, fmt=fmt),
+        name="posit_quantize_pallas",
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.uint32),
         grid=(m // bm, n // bn),
         in_specs=[spec],
@@ -64,6 +65,7 @@ def posit_dequantize_pallas(fmt: PositFormat, p, block=(64, 256),
     spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
     return pl.pallas_call(
         functools.partial(_dequant_kernel, fmt=fmt),
+        name="posit_dequantize_pallas",
         out_shape=jax.ShapeDtypeStruct(p.shape, jnp.float32),
         grid=(m // bm, n // bn),
         in_specs=[spec],

@@ -658,6 +658,7 @@ def posit_div_pallas(
     spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
     return pl.pallas_call(
         functools.partial(_kernel, fmt=fmt, variant=variant),
+        name="posit_div_pallas",
         out_shape=jax.ShapeDtypeStruct(px.shape, jnp.uint32),
         grid=grid,
         in_specs=[spec, spec],

@@ -342,6 +342,7 @@ def _flash_call(fmt, q, k, v, causal, window, q_offset, scale, variant,
         extra = []
     outs = pl.pallas_call(
         kernel,
+        name="posit_flash_attention",
         out_shape=out_shape,
         grid=(B * H, Sqp // bq),
         in_specs=[pl.BlockSpec((1, bq, hdp), lambda b, i: (b, i, 0))]
@@ -608,6 +609,7 @@ def _flash_backward(fmt: PositFormat, q, k, v, o, g, m, l,
             _flash_bwd_dq_kernel, fmt=fmt, variant=variant, causal=causal,
             window=window, q_offset=q_offset, scale=scale, bq=bq, bk=bk,
             nk=nk, sk_valid=Sk),
+        name="posit_flash_attention_bwd_dq",
         out_shape=jax.ShapeDtypeStruct((B * H, Sqp, hdp), jnp.float32),
         grid=(B * H, nq),
         in_specs=[
@@ -633,6 +635,7 @@ def _flash_backward(fmt: PositFormat, q, k, v, o, g, m, l,
             _flash_bwd_dkv_kernel, fmt=fmt, variant=variant, causal=causal,
             window=window, q_offset=q_offset, scale=scale, bq=bq, bk=bk,
             nq=nq, G=G, sk_valid=Sk),
+        name="posit_flash_attention_bwd_dkv",
         out_shape=2 * [jax.ShapeDtypeStruct((B * KV, Skp, hdp),
                                             jnp.float32)],
         grid=(B * KV, nk),

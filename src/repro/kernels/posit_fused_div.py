@@ -104,6 +104,7 @@ def posit_fused_div_pallas(
     spec = pl.BlockSpec((bm, bn), lambda i, j: (i, j))
     return pl.pallas_call(
         functools.partial(_fused_kernel, fmt=fmt, variant=variant),
+        name="posit_fused_div_pallas",
         out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
         grid=grid,
         in_specs=[spec, spec],
@@ -150,6 +151,7 @@ def posit_fused_div_rowwise_pallas(
     grid = (m // bm, n // bn)
     return pl.pallas_call(
         functools.partial(_rowwise_kernel, fmt=fmt, variant=variant),
+        name="posit_fused_div_rowwise_pallas",
         out_shape=jax.ShapeDtypeStruct(a.shape, jnp.float32),
         grid=grid,
         in_specs=[
@@ -210,6 +212,7 @@ def posit_softmax_fused_pallas(
     return pl.pallas_call(
         functools.partial(_softmax_kernel, fmt=fmt, variant=variant,
                           cols_valid=cols_valid),
+        name="posit_softmax_fused_pallas",
         out_shape=jax.ShapeDtypeStruct(x.shape, jnp.float32),
         grid=(m // bm,),
         in_specs=[pl.BlockSpec((bm, n), lambda i: (i, 0))],

@@ -297,6 +297,26 @@ Sharded serving currently requires the dense attention family,
 ``multi-device`` job (8 forced host devices, ``BENCH_PR10.json``) gate
 the contract.
 
+Spans, counters and executable names
+====================================
+Each solo admission runs inside a ``serve.admit`` profiler span
+(``jax.profiler.TraceAnnotation``; args ``rid``, ``prompt`` tokens,
+padded ``bucket`` length, ``wait_ms`` from submission) holding
+``serve.prefill`` (prefill dispatch and health read), ``serve.scatter``
+(the cache write) and ``serve.first_token`` (sample and host read); a
+packed admission inside ``serve.admit_pack`` (the args summed over the
+pack, plus ``segments``).  Each decode step runs inside ``serve.step``
+(args ``step``, ``active`` slots, ``live_rows`` and ``read_rows`` of KV)
+holding ``serve.dispatch`` (the host mirrors to the device, five eager
+``jit_convert_element_type`` executions, then the decode and sample
+dispatch), ``serve.sync`` (the (B, 2) readback) and ``serve.record``
+(the bookkeeping).  No span is open across a ``yield``, and every arg
+comes from host state: a span never syncs the device, and with the
+profiler off costs about a microsecond.  The spans land in the
+profiler's trace on the device ops' clock, where every engine
+executable shows as ``jit_serve_<name>``.  :meth:`ServeEngine.counters`
+sums the same increments over sessions.
+
 Static guarantees (proved, not sampled)
 =======================================
 ``python -m repro.analysis`` (the CI ``static-analysis`` job) proves the
@@ -331,6 +351,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import shard_map
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as PSpec
 
 from repro.models import transformer as T
@@ -379,6 +400,14 @@ def _pow2_ceil(n: int, floor: int = 1) -> int:
     return p
 
 
+def _named_jit(name: str, fn, **kw):
+    """``jax.jit`` under a stable name: the lowered module, and each of its
+    executions in a profiler trace, is ``jit_serve_<name>`` (``<name>`` as
+    :meth:`ServeEngine.executable_counts` keys it), never ``jit__lambda``."""
+    fn.__name__ = fn.__qualname__ = f"serve_{name}"
+    return jax.jit(fn, **kw)
+
+
 class FinishReason(str, enum.Enum):
     """Terminal status of a served request (see module docstring)."""
 
@@ -406,6 +435,11 @@ class ServeResult:
     ttft_ms: Optional[float] = None
     latency_ms: float = 0.0
 
+
+#: The cumulative counters of :meth:`ServeEngine.counters`; each is also a
+#: per-session ``last_serve_stats`` key.
+COUNTERS = ("decode_steps", "active_slot_steps", "admissions",
+            "prompt_tokens", "prefill_rows", "kv_rows_live", "kv_rows_read")
 
 #: Streaming events yielded by :meth:`ServeEngine.serve_stream`.
 TokenEvent = collections.namedtuple("TokenEvent", ("rid", "token"))
@@ -763,8 +797,11 @@ class _ServeState:
         self.packed_packs = 0        # packed admission dispatches
         self.packed_segments = 0     # real requests admitted packed
         self.packed_dummies = 0      # pad segments burned on pow2 rounding
-        self.ttfts: List[float] = []
+        self.prefill_rows = 0        # padded prompt rows prefilled
+        self.kv_rows_live = 0        # KV rows the active slots attend
+        self.kv_rows_read = 0        # KV rows decode attention scans
         self.token_lats: List[float] = []
+        self.closed = False          # counters folded into the engine's
 
     @property
     def drained(self) -> bool:
@@ -884,28 +921,37 @@ class ServeEngine:
         # decode always computes the (B,) health probe in-device
         # (with_health=True): it rides the same jitted call and the same
         # host transfer, so fault detection costs no extra sync.
-        self._decode = jax.jit(
+        self._decode = _named_jit(
+            "decode",
             sm(lambda p, c, t, i, s: T.decode_step(p, mcfg, c, t, i, s,
                                                    with_health=True),
                (ps, cs, rr, rr, rr), (rr, cs, rr)),
             donate_argnums=1)
-        self._prefill = jax.jit(
+        self._prefill = _named_jit(
+            "prefill",
             sm(lambda p, c, t, s: T.prefill(p, mcfg, {"tokens": t}, c, s),
                (ps, cs, rr, rr), (rr, cs)))
-        self._write_slot = jax.jit(
+        self._write_slot = _named_jit(
+            "write_slot",
             sm(lambda c, m, b: T.write_cache_slot(mcfg, c, m, b),
                (cs, cs, rr), cs),
             donate_argnums=0)
-        self._sample_full = jax.jit(self._sample_impl)
-        self._sample_greedy = jax.jit(self._greedy_impl)
+        self._sample_full = _named_jit(
+            "sample_full", lambda *a: self._sample_impl(*a))
+        self._sample_greedy = _named_jit(
+            "sample_greedy", lambda lg: self._greedy_impl(lg))
         # packed serve-loop samplers: one (B, 2) int32 [token, healthy]
-        self._sample_full_h = jax.jit(self._sample_h_impl)
-        self._sample_greedy_h = jax.jit(self._greedy_h_impl)
-        self._health = jax.jit(lambda lg: T.logits_health(cfg, lg))
+        self._sample_full_h = _named_jit(
+            "sample_full_h", lambda *a: self._sample_h_impl(*a))
+        self._sample_greedy_h = _named_jit(
+            "sample_greedy_h", lambda lg, h: self._greedy_h_impl(lg, h))
+        self._health = _named_jit(
+            "health", lambda lg: T.logits_health(cfg, lg))
         self._base_key = jax.random.PRNGKey(self.sc.seed)
         self.last_serve_stats = None    # measured counters of the last serve
         self.last_results: Optional[List[ServeResult]] = None
         self._st: Optional[_ServeState] = None
+        self._ended = dict.fromkeys(COUNTERS, 0)   # closed sessions' sums
 
         # ------------------------------------------------------ paged layout
         sc = self.sc
@@ -938,7 +984,8 @@ class ServeEngine:
             # the prefix tokens; a quantized cache stores rounded rows that
             # prefill does not attend, so sharing is disabled there
             self._share = not cfg.numerics.kv_cache_format
-            self._decode_paged = jax.jit(
+            self._decode_paged = _named_jit(
+                "decode_paged",
                 sm(lambda p, c, bt, t, i, s: T.decode_step(
                        p, mcfg, c, t, i, s, block_tables=bt,
                        with_health=True),
@@ -947,24 +994,28 @@ class ServeEngine:
             # static args cannot pass through shard_map: close over them
             # inside the jit trace (one shard_map per static value, cached
             # by the jit signature exactly as before)
-            self._prefill_t0 = jax.jit(
+            self._prefill_t0 = _named_jit(
+                "prefill_t0",
                 lambda p, c, t, s, t0: sm(
                     lambda p_, c_, t_, s_: T.prefill(
                         p_, mcfg, {"tokens": t_}, c_, s_, t0),
                     (ps, cs, rr, rr), (rr, cs))(p, c, t, s),
                 static_argnums=4)
-            self._write_blocks = jax.jit(
+            self._write_blocks = _named_jit(
+                "write_blocks",
                 sm(lambda c, m, bids, first: T.write_cache_blocks(
                        mcfg, c, m, bids, first),
                    (cs, cs, rr, rr), cs),
                 donate_argnums=0)
-            self._mini_prefix = jax.jit(
+            self._mini_prefix = _named_jit(
+                "mini_prefix",
                 lambda c, bids, rows: sm(
                     lambda c_, b_: T.mini_cache_with_prefix(mcfg, c_, b_,
                                                             rows),
                     (cs, rr), cs)(c, bids),
                 static_argnums=2)
-            self._scatter_pool = jax.jit(
+            self._scatter_pool = _named_jit(
+                "scatter_pool",
                 sm(lambda c, d, bt: T.scatter_dense_to_pool(mcfg, c, d, bt),
                    (cs, cs, rr), cs),
                 donate_argnums=0)
@@ -979,7 +1030,8 @@ class ServeEngine:
             # dense family: N prompts concatenated into ONE (1, N*P)
             # sequence, block-diagonal via segment ids; seg_len is static
             # (chunk/tile geometry derives from it)
-            self._prefill_packed = jax.jit(
+            self._prefill_packed = _named_jit(
+                "prefill_packed",
                 lambda p, c, t, pos, seg, last, P: sm(
                     lambda p_, c_, t_, pos_, seg_, last_: T.prefill_packed(
                         p_, mcfg, t_, c_, pos_, seg_, last_, P),
@@ -988,7 +1040,8 @@ class ServeEngine:
                 static_argnums=6)
             if self._paged:
                 # segment rows -> per-segment pool blocks in one scatter
-                self._scatter_segments = jax.jit(
+                self._scatter_segments = _named_jit(
+                    "scatter_segments",
                     lambda c, m, bids, P: sm(
                         lambda c_, m_, b_: T.scatter_segments_to_pool(
                             mcfg, c_, m_, b_, P),
@@ -996,14 +1049,16 @@ class ServeEngine:
                     donate_argnums=0, static_argnums=3)
                 # scanned families (moe): batch-axis pack, right-padded
                 # rows at start 0 with per-row last-logit capture
-                self._prefill_ragged = jax.jit(
+                self._prefill_ragged = _named_jit(
+                    "prefill_ragged",
                     sm(lambda p, c, t, s, last: T.prefill_batch_ragged(
                            p, mcfg, t, c, s, last),
                        (ps, cs, rr, rr, rr), (rr, cs)))
             else:
                 # one fused write of every segment into its slot (rows
                 # beyond the segment zero-fill, matching the solo mini)
-                self._write_slot_segments = jax.jit(
+                self._write_slot_segments = _named_jit(
+                    "write_slot_segments",
                     lambda c, m, slots, P: sm(
                         lambda c_, m_, s_: T.write_cache_slot_segments(
                             mcfg, c_, m_, s_, P),
@@ -1011,7 +1066,8 @@ class ServeEngine:
                     donate_argnums=0, static_argnums=3)
                 # scanned families: (N, P) rows through the existing
                 # batch-capable _prefill, scattered row-per-slot
-                self._write_slots = jax.jit(
+                self._write_slots = _named_jit(
+                    "write_slots",
                     sm(lambda c, m, slots: T.write_cache_slots(mcfg, c, m,
                                                                slots),
                        (cs, cs, rr), cs),
@@ -1184,6 +1240,40 @@ class ServeEngine:
                 fns.update(write_slot_segments=self._write_slot_segments,
                            write_slots=self._write_slots)
         return {k: f._cache_size() for k, f in fns.items()}
+
+    def counters(self) -> Dict[str, int]:
+        """Cumulative serving counters over every session this engine has
+        served, the live one included (:meth:`warmup`'s are left out):
+
+          ``decode_steps``       decode steps run
+          ``active_slot_steps``  active slots, summed over decode steps
+          ``admissions``         admission prefills (quarantined ones too)
+          ``prompt_tokens``      real prompt tokens of admitted requests
+          ``prefill_rows``       prompt rows prefilled, bucket padding and
+                                 a pack's dummy segments included
+          ``kv_rows_live``       KV rows the active slots attend
+                                 (``pos - start + 1`` each), summed over
+                                 decode steps
+          ``kv_rows_read``       KV rows decode attention scans
+                                 (``max_batch * max_seq`` a step)
+
+        A session's own counts are its ``last_serve_stats``; the same
+        increments are the args of the ``serve.admit`` and ``serve.step``
+        spans (see :meth:`serve_stream`)."""
+        out = dict(self._ended)
+        st = self._st
+        if st is not None and not st.closed:
+            for k in COUNTERS:
+                out[k] += getattr(st, k)
+        return out
+
+    def _close(self, st: _ServeState) -> None:
+        """Fold an ended session's counters into the engine's totals
+        (once, whether its stream ran to the end or was abandoned)."""
+        if not st.closed:
+            st.closed = True
+            for k in COUNTERS:
+                self._ended[k] += getattr(st, k)
 
     # ------------------------------------------------------- static batching
 
@@ -1463,6 +1553,8 @@ class ServeEngine:
         r = (request if isinstance(request, Request)
              else Request(np.asarray(request, np.int32), max_new=max_new))
         if self._st is None or self._st.drained:
+            if self._st is not None:
+                self._close(self._st)
             self._st = _ServeState(self)
         st = self._st
         try:
@@ -1553,37 +1645,62 @@ class ServeEngine:
                               self.sc.max_queue_wait_ms) if x is not None]
         return min(limits) if limits else None
 
+    def _admit_span(self, st: _ServeState, rid: int, P: int):
+        """The ``serve.admit`` span of one solo admission: the request's id,
+        its real prompt tokens, its padded length ``P`` and how long it
+        waited from submission (engine clock).  Counts the prefill rows."""
+        st.prefill_rows += P
+        return TraceAnnotation(
+            "serve.admit", rid=rid, prompt=len(st.reqs[rid].tokens),
+            bucket=P, wait_ms=self._now_ms() - st.t_submit[rid])
+
+    def _healthy(self, lg) -> bool:
+        """Solo admission's health probe; reading it back is the host sync
+        that waits out the prefill."""
+        return (not self.sc.health_checks
+                or bool(np.asarray(self._health(lg))[0]))
+
+    def _admission_fault(self, st: _ServeState, rid: int) -> FinishEvent:
+        """The request's own prompt already produces NaR/non-finite logits:
+        quarantine it at admission, before anything of it is published."""
+        now = self._now_ms()
+        st.t_admit[rid] = now
+        st.faults += 1
+        res = self._finish(st, rid, np.zeros(0, np.int32),
+                           FinishReason.FAULT,
+                           "non-finite prefill logits quarantined", now)
+        return FinishEvent(rid, res)
+
     def _admit_dense(self, st: _ServeState, slot: int, rid: int) -> List:
         sc = self.sc
         P, s0, budget = st.plans[rid]
         r = st.reqs[rid]
-        if st.mini_zero is None:
-            # zero batch=1 cache reused by every admission (prefill is
-            # pure, so the template never holds a previous request's rows)
-            st.mini_zero = self._place_cache(
-                T.init_cache(self.cfg, 1, sc.max_seq))
-        toks = np.zeros((1, P), np.int32)
-        toks[0, s0:] = r.tokens
-        # prefill into a fresh (zero) batch=1 cache, then scatter it
-        # into the freed slot — the other slots keep their rows and
-        # state and never stop decoding
-        lg, mini = self._prefill(self.params, st.mini_zero,
-                                 jnp.asarray(toks),
-                                 jnp.asarray([s0], jnp.int32))
-        st.admissions += 1
-        if sc.health_checks and not bool(np.asarray(self._health(lg))[0]):
-            # the request's own prompt already produces NaR/non-finite
-            # logits: quarantine at admission — the poisoned mini cache is
-            # discarded, never scattered into the shared slot cache
-            now = self._now_ms()
-            st.t_admit[rid] = now
-            st.faults += 1
-            res = self._finish(st, rid, np.zeros(0, np.int32),
-                               FinishReason.FAULT,
-                               "non-finite prefill logits quarantined", now)
-            return [FinishEvent(rid, res)]
-        st.cache = self._write_slot(st.cache, mini, jnp.int32(slot))
-        return self._finish_admission(st, slot, rid, lg, P, s0, budget)
+        with self._admit_span(st, rid, P):
+            if st.mini_zero is None:
+                # zero batch=1 cache reused by every admission (prefill is
+                # pure, so the template never holds a previous request's
+                # rows)
+                st.mini_zero = self._place_cache(
+                    T.init_cache(self.cfg, 1, sc.max_seq))
+            toks = np.zeros((1, P), np.int32)
+            toks[0, s0:] = r.tokens
+            # prefill into a fresh (zero) batch=1 cache, then scatter it
+            # into the freed slot — the other slots keep their rows and
+            # state and never stop decoding
+            with TraceAnnotation("serve.prefill"):
+                lg, mini = self._prefill(self.params, st.mini_zero,
+                                         jnp.asarray(toks),
+                                         jnp.asarray([s0], jnp.int32))
+                st.admissions += 1
+                healthy = self._healthy(lg)
+            if not healthy:
+                # the poisoned mini cache is discarded, never scattered
+                # into the shared slot cache
+                return [self._admission_fault(st, rid)]
+            with TraceAnnotation("serve.scatter"):
+                st.cache = self._write_slot(st.cache, mini, jnp.int32(slot))
+            st.prompt_tokens += len(r.tokens)
+            return self._finish_admission(st, slot, rid, lg, P, s0, budget)
 
     def _admit_paged(self, st: _ServeState, slot: int, rid: int):
         """Paged admission; ``(False, [])`` = not enough free blocks
@@ -1624,64 +1741,65 @@ class ServeEngine:
                 alloc.decref(b)
             return False, []
         rows = total * bs
-        if t0:
-            mini = self._mini_prefix(st.cache,
-                                     jnp.asarray(shared, jnp.int32),
-                                     rows)
-        else:
-            if rows not in st.mini_zeros:
-                st.mini_zeros[rows] = self._place_cache(
-                    T.init_cache(self.cfg, 1, rows))
-            mini = st.mini_zeros[rows]
-        lg, mini = self._prefill_t0(
-            self.params, mini,
-            jnp.asarray(np.asarray(r.tokens, np.int32)[None]),
-            jnp.zeros((1,), jnp.int32), t0)
-        st.admissions += 1
-        if sc.health_checks and not bool(np.asarray(self._health(lg))[0]):
-            # quarantine BEFORE the pool write and BEFORE registration: a
-            # poisoned page must never be published for prefix sharing —
-            # and the shared prefix pages this prefill READ are themselves
-            # suspect, so evict them from the prefix table too
-            for b in owned:
-                alloc.decref(b)
-            for b in shared[:s_blk]:
-                alloc.decref(b)
-                alloc.quarantine(b)
-            now = self._now_ms()
-            st.t_admit[rid] = now
-            st.faults += 1
-            res = self._finish(st, rid, np.zeros(0, np.int32),
-                               FinishReason.FAULT,
-                               "non-finite prefill logits quarantined", now)
-            return True, [FinishEvent(rid, res)]
-        st.cache = self._write_blocks(st.cache, mini,
-                                      jnp.asarray(owned, jnp.int32),
-                                      jnp.int32(s_blk))
-        chain = shared[:s_blk] + owned
-        if self._share:
-            alloc.register_prefix(toks, chain)
-        st.bt_host[slot, :] = 0
-        st.bt_host[slot, :total] = chain
-        st.slot_blocks[slot] = chain
-        st.hit_tokens += t0
-        st.fill_tokens += plen - t0
-        st.prompt_tokens += plen
-        st.owned_total += len(owned)
-        st.shared_total += s_blk
-        st.peak_blocks = max(st.peak_blocks, alloc.blocks_in_use())
-        return True, self._finish_admission(st, slot, rid, lg, plen, 0,
-                                            budget)
+        with self._admit_span(st, rid, plen):
+            with TraceAnnotation("serve.prefill"):
+                if t0:
+                    mini = self._mini_prefix(st.cache,
+                                             jnp.asarray(shared, jnp.int32),
+                                             rows)
+                else:
+                    if rows not in st.mini_zeros:
+                        st.mini_zeros[rows] = self._place_cache(
+                            T.init_cache(self.cfg, 1, rows))
+                    mini = st.mini_zeros[rows]
+                lg, mini = self._prefill_t0(
+                    self.params, mini,
+                    jnp.asarray(np.asarray(r.tokens, np.int32)[None]),
+                    jnp.zeros((1,), jnp.int32), t0)
+                st.admissions += 1
+                healthy = self._healthy(lg)
+            if not healthy:
+                # quarantine BEFORE the pool write and BEFORE registration:
+                # a poisoned page must never be published for prefix
+                # sharing — and the shared prefix pages this prefill READ
+                # are themselves suspect, so evict them from the prefix
+                # table too
+                for b in owned:
+                    alloc.decref(b)
+                for b in shared[:s_blk]:
+                    alloc.decref(b)
+                    alloc.quarantine(b)
+                return True, [self._admission_fault(st, rid)]
+            with TraceAnnotation("serve.scatter"):
+                st.cache = self._write_blocks(st.cache, mini,
+                                              jnp.asarray(owned, jnp.int32),
+                                              jnp.int32(s_blk))
+            chain = shared[:s_blk] + owned
+            if self._share:
+                alloc.register_prefix(toks, chain)
+            st.bt_host[slot, :] = 0
+            st.bt_host[slot, :total] = chain
+            st.slot_blocks[slot] = chain
+            st.hit_tokens += t0
+            st.fill_tokens += plen - t0
+            st.prompt_tokens += plen
+            st.owned_total += len(owned)
+            st.shared_total += s_blk
+            st.peak_blocks = max(st.peak_blocks, alloc.blocks_in_use())
+            return True, self._finish_admission(st, slot, rid, lg, plen, 0,
+                                                budget)
 
     def _finish_admission(self, st: _ServeState, slot: int, rid: int,
                           lg, P: int, s0: int, budget: int) -> List:
         """Shared admission tail: sample the prefill token, then arm the
         slot.  Returns the stream events this admission produced."""
-        key_r = self._request_key(st.req_key[rid])
-        t0 = self._sample(lg, np.asarray([st.req_temp[rid]], np.float32),
-                          key_r[None], jnp.zeros((1,), jnp.int32))
-        return self._arm_slot(st, slot, rid, int(np.asarray(t0)[0, 0]),
-                              np.asarray(key_r), P, s0, budget)
+        with TraceAnnotation("serve.first_token"):
+            key_r = self._request_key(st.req_key[rid])
+            t0 = self._sample(lg, np.asarray([st.req_temp[rid]], np.float32),
+                              key_r[None], jnp.zeros((1,), jnp.int32))
+            tok = int(np.asarray(t0)[0, 0])
+        return self._arm_slot(st, slot, rid, tok, np.asarray(key_r), P, s0,
+                              budget)
 
     def _arm_slot(self, st: _ServeState, slot: int, rid: int, tok: int,
                   key_r, P: int, s0: int, budget: int) -> List:
@@ -1698,7 +1816,6 @@ class ServeEngine:
         now = self._now_ms()
         st.t_admit.setdefault(rid, now)
         st.ttft[rid] = now - st.t_submit.get(rid, now)
-        st.ttfts.append(st.ttft[rid])
         st.last_tok_ms[slot] = now
         events: List = [TokenEvent(rid, tok)]
         if st.sched.record_one(slot, tok, st.req_eos[rid]):
@@ -1757,13 +1874,19 @@ class ServeEngine:
         for P, rids in packs:
             slots = [int(s) for s in free[si:si + len(rids)]]
             if self._paged:
-                done, evs = self._admit_packed_paged(st, slots, rids, P)
-            elif self.cfg.family == "dense":
-                evs = self._admit_packed_dense(st, slots, rids, P)
-                done = rids
+                plans = self._alloc_packed_paged(st, rids)
+                done = [p[0] for p in plans]
+                if not done:
+                    break            # pool starvation: defer the rest
             else:
-                evs = self._admit_packed_batch(st, slots, rids, P)
                 done = rids
+            with self._pack_span(st, done, P):
+                if self._paged:
+                    evs = self._admit_packed_paged(st, slots, plans, P)
+                elif self.cfg.family == "dense":
+                    evs = self._admit_packed_dense(st, slots, rids, P)
+                else:
+                    evs = self._admit_packed_batch(st, slots, rids, P)
             admitted.update(done)
             si += len(done)
             events.extend(evs)
@@ -1773,6 +1896,19 @@ class ServeEngine:
             st.queue = collections.deque(
                 r for r in st.queue if r not in admitted)
         return events
+
+    def _pack_span(self, st: _ServeState, rids: List[int], P: int):
+        """The ``serve.admit_pack`` span of one packed admission: the solo
+        span's args summed over the pack (``bucket``: the rows of its
+        power-of-two segment count, dummies included), its first ``rid``
+        and its real ``segments``.  Counts the prefill rows."""
+        now = self._now_ms()
+        rows = _pow2_ceil(len(rids)) * P
+        st.prefill_rows += rows
+        return TraceAnnotation(
+            "serve.admit_pack", rid=rids[0], segments=len(rids),
+            prompt=sum(len(st.reqs[r].tokens) for r in rids), bucket=rows,
+            wait_ms=sum(now - st.t_submit[r] for r in rids))
 
     def _admit_packed_dense(self, st: _ServeState, slots: List[int],
                             rids: List[int], P: int) -> List:
@@ -1848,26 +1984,15 @@ class ServeEngine:
                                  [st.plans[rid][0] for rid in rids],
                                  [st.plans[rid][1] for rid in rids])
 
-    def _admit_packed_paged(self, st: _ServeState, slots: List[int],
-                            rids: List[int], W: int):
-        """Paged packed admission; returns ``(admitted_rids, events)``.
-
-        Walks the pack FIFO mapping shared prefix blocks and allocating
-        owned ones per request, stopping at the first the pool cannot
-        satisfy (it and everything behind it stay queued — solo deferral
-        semantics).  Segments are RIGHT-padded to the block-aligned
-        width ``W`` at start 0 (the sharing contract) and FULLY
-        recomputed (t0=0: intra-pack gathering would need the pack's own
-        pages before they are written; full recompute is bit-identical
-        by the suffix-prefill contract), then scattered block-wise in
-        one call — shared prefix blocks are mapped, never rewritten
-        (first-writer-wins), and a faulted segment's rows go to the
-        block-0 sink.  Prefix registration happens per segment AFTER the
-        health check, exactly as solo."""
-        sc = self.sc
+    def _alloc_packed_paged(self, st: _ServeState, rids: List[int]) -> List:
+        """Block plans of a paged pack: walks it FIFO mapping shared
+        prefix blocks and allocating owned ones per request, stopping at
+        the first the pool cannot satisfy (it and everything behind it
+        stay queued — solo deferral semantics).  Returns ``[(rid, plen,
+        shared_mapped, owned, t0, total)]`` for the requests it covers."""
         alloc = st.alloc
-        bs = sc.block_size
-        plans = []            # (rid, plen, shared_mapped, owned, t0, total)
+        bs = self.sc.block_size
+        plans = []
         for rid in rids:
             plen, _, _ = st.plans[rid]
             toks_t = tuple(int(t) for t in st.reqs[rid].tokens)
@@ -1888,8 +2013,25 @@ class ServeEngine:
                     alloc.decref(b)
                 break
             plans.append((rid, plen, shared[:s_blk], owned, t0, total))
-        if not plans:
-            return [], []
+        return plans
+
+    def _admit_packed_paged(self, st: _ServeState, slots: List[int],
+                            plans: List, W: int) -> List:
+        """Paged packed admission of the requests :meth:`_alloc_packed_paged`
+        found blocks for.
+
+        Segments are RIGHT-padded to the block-aligned
+        width ``W`` at start 0 (the sharing contract) and FULLY
+        recomputed (t0=0: intra-pack gathering would need the pack's own
+        pages before they are written; full recompute is bit-identical
+        by the suffix-prefill contract), then scattered block-wise in
+        one call — shared prefix blocks are mapped, never rewritten
+        (first-writer-wins), and a faulted segment's rows go to the
+        block-0 sink.  Prefix registration happens per segment AFTER the
+        health check, exactly as solo."""
+        sc = self.sc
+        alloc = st.alloc
+        bs = sc.block_size
         n_real = len(plans)
         N = _pow2_ceil(n_real)
         nd = N - n_real
@@ -1945,14 +2087,7 @@ class ServeEngine:
                 for b in shared_m:
                     alloc.decref(b)
                     alloc.quarantine(b)
-                now = self._now_ms()
-                st.t_admit[rid] = now
-                st.faults += 1
-                res = self._finish(st, rid, np.zeros(0, np.int32),
-                                   FinishReason.FAULT,
-                                   "non-finite prefill logits quarantined",
-                                   now)
-                events.append(FinishEvent(rid, res))
+                events.append(self._admission_fault(st, rid))
                 continue
             chain = shared_m + owned
             if self._share:
@@ -1971,7 +2106,7 @@ class ServeEngine:
             events.extend(self._arm_slot(st, slot, rid, int(toks_s[nd + j, 0]),
                                          keys[nd + j], plen, 0,
                                          st.plans[rid][2]))
-        return [p[0] for p in plans], events
+        return events
 
     def _sample_pack(self, st: _ServeState, rids: List[int], nd: int,
                      N: int, lg):
@@ -2005,15 +2140,9 @@ class ServeEngine:
             i = nd + j
             st.admissions += 1
             if sc.health_checks and not healthy[i]:
-                now = self._now_ms()
-                st.t_admit[rid] = now
-                st.faults += 1
-                res = self._finish(st, rid, np.zeros(0, np.int32),
-                                   FinishReason.FAULT,
-                                   "non-finite prefill logits quarantined",
-                                   now)
-                events.append(FinishEvent(rid, res))
+                events.append(self._admission_fault(st, rid))
                 continue
+            st.prompt_tokens += len(st.reqs[rid].tokens)
             events.extend(self._arm_slot(st, slots[j], rid,
                                          int(toks[i, 0]), keys[i],
                                          Ps[j], s0s[j],
@@ -2051,7 +2180,7 @@ class ServeEngine:
         # packs as _pow2_ceil(r), dummies filling the difference)
         bins = sorted({_pow2_ceil(r) for r in range(1, sc.max_batch + 1)})
         sizes = [min(N, sc.max_batch) for N in bins]
-        saved = (self.last_serve_stats, self.last_results)
+        saved = (self.last_serve_stats, self.last_results, dict(self._ended))
         temp = 0.0 if temperature is None else float(temperature)
         for P in plens:
             plen = max(1, P - 1)      # lands in bucket P
@@ -2064,7 +2193,7 @@ class ServeEngine:
                         for _ in range(r)]
                 self.serve(reqs)
         self._st = None
-        self.last_serve_stats, self.last_results = saved
+        self.last_serve_stats, self.last_results, self._ended = saved
         return self.executable_counts()
 
     def _release_blocks(self, st: _ServeState, slot: int,
@@ -2190,13 +2319,34 @@ class ServeEngine:
                 yield st.pending.pop(0)
             if not st.sched.any_active:
                 continue    # admitted requests may finish at token 0
-            st.decode_steps += 1
-            st.active_slot_steps += int(st.sched.active.sum())
+            self._step(st)
+            # the step's bookkeeping is fully applied — NOW hand events out
+            # (tokens before finishes; snapshot() is safe at every yield)
+            while st.pending:
+                yield st.pending.pop(0)
+        self._finalize_stats(st)
 
+    def _step(self, st: _ServeState) -> None:
+        """One decode step over all slots, its events left in
+        ``st.pending``; the ``serve.step`` span (``step``, ``active``
+        slots, ``live_rows`` and ``read_rows`` of KV, all read from host
+        mirrors) and its ``serve.dispatch``, ``serve.sync`` and
+        ``serve.record`` phases."""
+        sc = self.sc
+        act = st.sched.active
+        n_act = int(act.sum())
+        live = int((st.pos[act] - st.start[act]).sum()) + n_act
+        read = sc.max_batch * sc.max_seq
+        st.decode_steps += 1
+        st.active_slot_steps += n_act
+        st.kv_rows_live += live
+        st.kv_rows_read += read
+        with TraceAnnotation("serve.step", step=st.decode_steps,
+                             active=n_act, live_rows=live, read_rows=read):
             if self._paged:
                 # grow each active slot's table before the row it is about
                 # to write crosses into an unmapped block
-                for slot in np.flatnonzero(st.sched.active):
+                for slot in np.flatnonzero(act):
                     need = int(st.pos[slot]) // sc.block_size
                     if need >= len(st.slot_blocks[slot]):
                         b = st.alloc.alloc()  # pool sized: never fails here
@@ -2204,85 +2354,91 @@ class ServeEngine:
                         st.bt_host[slot, need] = b
                         st.peak_blocks = max(st.peak_blocks,
                                              st.alloc.blocks_in_use())
-
             # ONE decode step for ALL slots at their own positions + ONE
             # vectorized sample; a single (B, 2) transfer back per step
             # carrying [token, healthy] per slot.
             # jnp.array COPIES each host mirror at hand-off: jnp.asarray
             # would zero-copy alias the numpy buffers on CPU, racing the
-            # async dispatch against the in-place updates below
-            if self._paged:
-                lg, st.cache, health = self._decode_paged(
-                    self.params, st.cache, jnp.array(st.bt_host),
-                    jnp.array(st.cur), jnp.array(st.pos),
-                    jnp.array(st.start))
-            else:
-                lg, st.cache, health = self._decode(
-                    self.params, st.cache, jnp.array(st.cur),
-                    jnp.array(st.pos), jnp.array(st.start))
-            packed = self._sample_packed(lg, health, st.temps,
-                                         jnp.array(st.keys),
-                                         jnp.array(st.steps))
+            # async dispatch against the in-place updates below.  Each
+            # copy is an eager ``jit_convert_element_type`` execution.
+            with TraceAnnotation("serve.dispatch"):
+                if self._paged:
+                    lg, st.cache, health = self._decode_paged(
+                        self.params, st.cache, jnp.array(st.bt_host),
+                        jnp.array(st.cur), jnp.array(st.pos),
+                        jnp.array(st.start))
+                else:
+                    lg, st.cache, health = self._decode(
+                        self.params, st.cache, jnp.array(st.cur),
+                        jnp.array(st.pos), jnp.array(st.start))
+                packed = self._sample_packed(lg, health, st.temps,
+                                             jnp.array(st.keys),
+                                             jnp.array(st.steps))
             # sync BEFORE mutating the pos/steps mirrors: under async
             # dispatch the jnp.array host->device copies above may still
             # be pending, and an in-place bump here would let the
             # in-flight step read the NEXT step's values (a real race —
             # it fired on the categorical sampler's ``steps`` input)
-            arr = np.asarray(packed)
-            np.minimum(st.pos + 1, sc.max_seq - 1, out=st.pos)
-            st.steps += 1
-            tok_h = arr[:, 0].astype(np.int32)
-            healthy = arr[:, 1].astype(bool)
-            st.cur = tok_h[:, None].copy()
-            now = self._now_ms()
+            with TraceAnnotation("serve.sync"):
+                arr = np.asarray(packed)
+            with TraceAnnotation("serve.record"):
+                self._record(st, arr)
 
-            # NaR / non-finite quarantine — BEFORE record(), so the faulted
-            # slot's garbage token never lands in its output.  Other slots
-            # are untouched: the model is batch-composition invariant, so
-            # their logits (and tokens) are bit-identical to a clean run.
-            if sc.health_checks:
-                for slot in np.flatnonzero(st.sched.active & ~healthy):
-                    rid = int(st.sched.slot_req[slot])
-                    st.faults += 1
-                    res = self._evict(st, int(slot), rid, FinishReason.FAULT,
-                                      "non-finite logits quarantined "
-                                      "mid-decode", now)
-                    emit(FinishEvent(rid, res))
+    def _record(self, st: _ServeState, arr: np.ndarray) -> None:
+        """A decode step's bookkeeping from its (B, 2) ``[token, healthy]``
+        readback: quarantine, record, evictions, the deadline sweep, and
+        the step's events into ``st.pending``."""
+        sc = self.sc
+        emit = st.pending.append
+        np.minimum(st.pos + 1, sc.max_seq - 1, out=st.pos)
+        st.steps += 1
+        tok_h = arr[:, 0].astype(np.int32)
+        healthy = arr[:, 1].astype(bool)
+        st.cur = tok_h[:, None].copy()
+        now = self._now_ms()
 
-            act = np.flatnonzero(st.sched.active)
-            finished = st.sched.record(tok_h, st.eos)
-            token_events = []
-            for slot in act:
+        # NaR / non-finite quarantine — BEFORE record(), so the faulted
+        # slot's garbage token never lands in its output.  Other slots
+        # are untouched: the model is batch-composition invariant, so
+        # their logits (and tokens) are bit-identical to a clean run.
+        if sc.health_checks:
+            for slot in np.flatnonzero(st.sched.active & ~healthy):
                 rid = int(st.sched.slot_req[slot])
-                if st.last_tok_ms[slot] > 0:
-                    st.token_lats.append(now - st.last_tok_ms[slot])
-                st.last_tok_ms[slot] = now
-                token_events.append(TokenEvent(rid, int(tok_h[slot])))
-            finish_events = []
-            for slot in finished:
-                rid = int(st.sched.slot_req[slot])
-                reason = (FinishReason.EOS if tok_h[slot] == st.eos[slot]
-                          else FinishReason.MAX_NEW)
-                res = self._evict(st, int(slot), rid, reason, "", now)
+                st.faults += 1
+                res = self._evict(st, int(slot), rid, FinishReason.FAULT,
+                                  "non-finite logits quarantined "
+                                  "mid-decode", now)
+                emit(FinishEvent(rid, res))
+
+        act = np.flatnonzero(st.sched.active)
+        finished = st.sched.record(tok_h, st.eos)
+        token_events = []
+        for slot in act:
+            rid = int(st.sched.slot_req[slot])
+            if st.last_tok_ms[slot] > 0:
+                st.token_lats.append(now - st.last_tok_ms[slot])
+            st.last_tok_ms[slot] = now
+            token_events.append(TokenEvent(rid, int(tok_h[slot])))
+        finish_events = []
+        for slot in finished:
+            rid = int(st.sched.slot_req[slot])
+            reason = (FinishReason.EOS if tok_h[slot] == st.eos[slot]
+                      else FinishReason.MAX_NEW)
+            res = self._evict(st, int(slot), rid, reason, "", now)
+            finish_events.append(FinishEvent(rid, res))
+        # in-flight deadline sweep (after record: the step's token is
+        # part of the partial output)
+        for slot in np.flatnonzero(st.sched.active):
+            rid = int(st.sched.slot_req[slot])
+            dl = st.reqs[rid].deadline_ms
+            if dl is not None and now - st.t_submit[rid] > dl:
+                st.deadline_evictions += 1
+                res = self._evict(st, int(slot), rid,
+                                  FinishReason.DEADLINE,
+                                  "deadline exceeded mid-decode", now)
                 finish_events.append(FinishEvent(rid, res))
-            # in-flight deadline sweep (after record: the step's token is
-            # part of the partial output)
-            for slot in np.flatnonzero(st.sched.active):
-                rid = int(st.sched.slot_req[slot])
-                dl = st.reqs[rid].deadline_ms
-                if dl is not None and now - st.t_submit[rid] > dl:
-                    st.deadline_evictions += 1
-                    res = self._evict(st, int(slot), rid,
-                                      FinishReason.DEADLINE,
-                                      "deadline exceeded mid-decode", now)
-                    finish_events.append(FinishEvent(rid, res))
-            # the step's bookkeeping is fully applied — NOW hand events out
-            # (tokens before finishes; snapshot() is safe at every yield)
-            for ev in token_events + finish_events:
-                emit(ev)
-            while st.pending:
-                yield st.pending.pop(0)
-        self._finalize_stats(st)
+        for ev in token_events + finish_events:
+            emit(ev)
 
     def _finalize_stats(self, st: _ServeState) -> None:
         # measured scheduler counters (e.g. the decode-throughput benchmark
@@ -2300,19 +2456,19 @@ class ServeEngine:
             "shed": st.shed,
             "finish_reasons": collections.Counter(
                 r.finish.value for r in st.results.values()),
-            "ttft_ms": list(st.ttfts),
+            "ttft_ms": list(st.ttft.values()),
             "token_latency_ms": list(st.token_lats),
             "packed_prefill": self._packed,
             "packed_packs": st.packed_packs,
             "packed_segments": st.packed_segments,
             "packed_dummies": st.packed_dummies,
+            **{k: getattr(st, k) for k in COUNTERS},
         }
         if self._paged:
             stats.update({
                 "block_size": sc.block_size,
                 "pool_blocks": self._num_blocks - 1,
                 "peak_blocks_in_use": st.peak_blocks,
-                "prompt_tokens": st.prompt_tokens,
                 "prefill_tokens": st.fill_tokens,
                 "prefix_hit_tokens": st.hit_tokens,
                 "prefix_hit_rate": st.hit_tokens / max(st.prompt_tokens, 1),
@@ -2322,6 +2478,7 @@ class ServeEngine:
                 "prefix_matches": st.alloc.hits,
             })
         self.last_serve_stats = stats
+        self._close(st)
 
     # -------------------------------------------------- snapshot / restore
 
@@ -2386,8 +2543,8 @@ class ServeEngine:
                                    "prompt_tokens", "owned_total",
                                    "shared_total", "peak_blocks",
                                    "packed_packs", "packed_segments",
-                                   "packed_dummies")},
-            "ttfts": list(st.ttfts),
+                                   "packed_dummies", "prefill_rows",
+                                   "kv_rows_live", "kv_rows_read")},
             "token_lats": list(st.token_lats),
             "cache": jax.device_get(st.cache),
         }
@@ -2452,7 +2609,6 @@ class ServeEngine:
         st.last_tok_ms = np.where(el > 0, now - el, 0.0)
         for k, v in snap["counters"].items():
             setattr(st, k, v)
-        st.ttfts = list(snap["ttfts"])
         st.token_lats = list(snap["token_lats"])
         # jnp.array COPIES the host leaves: the donated decode step may not
         # alias a buffer the snapshot dict still references
@@ -2470,4 +2626,8 @@ class ServeEngine:
             a.owner = dict(sa["owner"])
             a.hits, a.lookups = sa["hits"], sa["lookups"]
             st.alloc = a
+        # the replaced session's counts stay in counters(); the restored
+        # one brings the counts it was snapshotted with
+        if self._st is not None:
+            self._close(self._st)
         self._st = st
