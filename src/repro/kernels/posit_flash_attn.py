@@ -40,6 +40,18 @@ riding the existing per-sequence scalar plumbing, not a new kernel: the
     window masks (added to the static ``q_offset``) — per-slot decode
     positions, so slots at heterogeneous offsets share one kernel launch.
 
+Live tiles only: each program scans just the KV tiles ``[lo, hi)`` that
+can hold a key one of its queries attends (:func:`kv_tile_bounds`, from
+``kv_start``, ``kv_len``, ``q_pos`` and the causal / window masks), not
+all of ``max_seq``; a decode step then costs each slot about its own
+length.  A tile whose every key is masked is an exact no-op of the
+``(m, l, acc)`` recurrence (``m`` stays, ``corr = exp(0) = 1``, ``p =
+0``), so skipping it leaves every output and every saved residual bit
+for bit as the full scan gave them.  Value rows outside ``[kv_start,
+kv_len)`` in a scanned tile are zeroed, so a NaN there cannot reach
+``acc`` through ``0 * NaN``.  The engine counts the rows it scans with
+the same rule (:func:`decode_rows_scanned`).
+
 Backward (recompute style, the flash-attention backward): the forward
 additionally saves per-row residuals ``(m, l)`` — the online-softmax row
 max and row sum, i.e. the logsumexp in factored form ``lse = m + log l`` —
@@ -82,6 +94,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -107,9 +120,35 @@ def _minpos_eps(fmt: PositFormat) -> float:
     return float(2.0 ** -min(fmt.max_scale, 126))
 
 
+def kv_tile_bounds(kv_start, kv_len, q_first, q_last, *, bk: int,
+                   sk_valid: int, causal: bool, window: int, xp=jnp):
+    """The KV tiles ``[lo, hi)`` of width ``bk`` that can hold a key some
+    query of a q tile attends, for query positions ``q_first..q_last``.
+
+    Every key of a tile outside the range is masked for every query row:
+    it lies before ``kv_start``, at or past ``min(kv_len, sk_valid)``,
+    after ``q_last`` (``causal``), or at or before ``q_first - window``
+    (sliding ``window``).  Skipping such a tile changes nothing in the
+    forward kernel's ``(m, l, acc)`` recurrence: its scores are all
+    ``_NEG_INF``, so ``m`` stays, ``corr = exp(0) = 1`` and ``p = 0``
+    leave ``l`` and ``acc`` as they were.  ``hi <= lo`` means no tile.
+
+    Pure integer arithmetic on nonnegative operands, so it runs on the
+    kernel's SMEM scalars (``xp=jnp``) and on host arrays (``xp=np``)
+    alike; :func:`decode_rows_scanned` is the host side."""
+    lo = xp.maximum(kv_start, 0) // bk
+    if window:
+        lo = xp.maximum(lo, xp.maximum(q_first - window + 1, 0) // bk)
+    end = xp.minimum(kv_len, sk_valid)
+    if causal:
+        end = xp.minimum(end, q_last + 1)
+    hi = (xp.maximum(end, 0) + bk - 1) // bk
+    return lo, hi
+
+
 def _flash_kernel(*refs,
                   fmt: PositFormat, variant: str, causal: bool, window: int,
-                  q_offset: int, scale: float, bq: int, bk: int, nk: int,
+                  q_offset: int, scale: float, bq: int, bk: int,
                   sk_valid: int, save_res: bool, pages: int = 0,
                   n_heads: int = 0, kv_heads: int = 0, group: int = 1,
                   num_blocks: int = 0, bt_cols: int = 0,
@@ -133,10 +172,12 @@ def _flash_kernel(*refs,
     q = q_ref[0]                                    # (bq, hdp) f32
     kv_start = ks_ref[seq]                          # SMEM scalars (B,) int32
     kv_len = jnp.minimum(kl_ref[seq], sk_valid)     # per-sequence valid rows
-    iq = pl.program_id(1)
-    q_pos = qp_ref[seq] + q_offset + iq * bq + jax.lax.broadcasted_iota(
-        jnp.int32, (bq, 1), 0)
+    q_first = qp_ref[seq] + q_offset + pl.program_id(1) * bq
+    q_pos = q_first + jax.lax.broadcasted_iota(jnp.int32, (bq, 1), 0)
     seg_q = sq_ref[0][:, :1] if has_seg else None   # (bq, 1) int32
+    lo, hi = kv_tile_bounds(kv_start, kv_len, q_first, q_first + bq - 1,
+                            bk=bk, sk_valid=sk_valid, causal=causal,
+                            window=window)
 
     m0 = jnp.full((bq, 1), _NEG_INF, dtype=jnp.float32)
     l0 = jnp.zeros((bq, 1), dtype=jnp.float32)
@@ -165,6 +206,11 @@ def _flash_kernel(*refs,
         else:
             kj = k_ref[0, pl.ds(j * bk, bk), :]     # (bk, hdp)
             vj = v_ref[0, pl.ds(j * bk, bk), :]
+        # zero the value rows outside [kv_start, kv_len): their p is 0,
+        # and 0 * NaN would poison acc.  Finite rows gave exact zeros
+        # already, so this changes no result.
+        row = j * bk + jax.lax.broadcasted_iota(jnp.int32, (bk, 1), 0)
+        vj = jnp.where((row < kv_len) & (row >= kv_start), vj, 0.0)
         s = jax.lax.dot_general(
             q, kj, (((1,), (1,)), ((), ())),
             preferred_element_type=jnp.float32) * scale     # (bq, bk)
@@ -189,7 +235,9 @@ def _flash_kernel(*refs,
             preferred_element_type=jnp.float32)
         return m_new, l_new, acc_new
 
-    m, l, acc = jax.lax.fori_loop(0, nk, kv_step, (m0, l0, a0))
+    # only the tiles that can hold an unmasked key: a skipped tile is an
+    # exact no-op of the recurrence (see kv_tile_bounds)
+    m, l, acc = jax.lax.fori_loop(lo, hi, kv_step, (m0, l0, a0))
 
     # Final normalizer through the SRT datapath: l is a (bq, 1) per-row
     # divisor, quantized and decoded once per query row (rowwise division).
@@ -244,7 +292,7 @@ def _flash_call(fmt, q, k, v, causal, window, q_offset, scale, variant,
         # k/v are global block pools (num_blocks, block_size, KV, hd);
         # the virtual kv length is the table width times the block size,
         # which the engine keeps equal to the dense max_seq — so the tile
-        # geometry (bq, bk, nk) below matches the dense layout exactly and
+        # geometry (bq, bk) below matches the dense layout exactly and
         # the kv scan accumulates bit-identically.
         NB, bsz, KV, _ = k.shape
         Sk = block_tables.shape[1] * bsz
@@ -257,7 +305,6 @@ def _flash_call(fmt, q, k, v, causal, window, q_offset, scale, variant,
 
     bq, bk, Sqp, Skp, hdp = _tile_params(Sq, Sk, hd, block_q, block_k)
     qf = _to_kernel_layout(q, Sqp, hdp)
-    nk = Skp // bk
     paged_kw = {}
     if paged:
         assert not save_res, "paged attention is forward/decode-only"
@@ -317,8 +364,8 @@ def _flash_call(fmt, q, k, v, causal, window, q_offset, scale, variant,
 
     kernel = functools.partial(
         _flash_kernel, fmt=fmt, variant=variant, causal=causal,
-        window=window, q_offset=q_offset, scale=scale, bq=bq, bk=bk,
-        nk=nk, sk_valid=Sk, save_res=save_res, has_seg=has_seg, n_heads=H,
+        window=window, q_offset=int(q_offset), scale=scale, bq=bq, bk=bk,
+        sk_valid=Sk, save_res=save_res, has_seg=has_seg, n_heads=H,
         **paged_kw)
     out_shape = [jax.ShapeDtypeStruct((B * H, Sqp, hdp), jnp.float32)]
     out_specs = [pl.BlockSpec((1, bq, hdp), lambda b, i: (b, i, 0))]
@@ -400,6 +447,12 @@ def posit_flash_attention(
     static ``q_offset``).  The serving engine's per-slot decode passes
     ``q_pos = pos`` and ``kv_len = pos + 1`` so every slot attends exactly
     its own written cache rows at its own offset, in one compiled kernel.
+    Tiles that lie wholly outside ``[kv_start, kv_len)`` (or wholly past
+    the causal / window reach of a q tile) are not scanned at all: every
+    key in them is masked, which makes them exact no-ops of the online
+    softmax, so the result is bit-identical to scanning them.  Value rows
+    outside the range are zeroed in the tiles that are scanned, so
+    non-finite values there cannot leak into the output.
 
     ``block_tables`` switches the kv side to the PAGED layout: ``k``/``v``
     become global block pools ``(num_blocks, block_size, KV, hd)`` and
@@ -427,6 +480,20 @@ def posit_flash_attention(
                        variant, interpret, block_q, block_k,
                        vmem_limit_bytes, False, kv_start, kv_len, q_pos,
                        block_tables, seg_q, seg_kv)
+
+
+def decode_rows_scanned(pos, start, max_seq: int, block_k: int = 128):
+    """Per slot, the KV rows in the tiles one decode launch scans, on the
+    host: the kernel's own :func:`kv_tile_bounds` for the serving decode
+    call (one query at ``q_pos = pos``, ``kv_len = pos + 1``, ``kv_start =
+    start``, causal, no window, ``max_seq`` keys in tiles of ``block_k``),
+    whole tiles counted."""
+    pos = np.asarray(pos, np.int64)
+    bq, bk = _tile_params(1, max_seq, 1, 128, block_k)[:2]
+    lo, hi = kv_tile_bounds(np.asarray(start, np.int64), pos + 1, pos,
+                            pos + bq - 1, bk=bk, sk_valid=max_seq,
+                            causal=True, window=0, xp=np)
+    return np.maximum(hi - lo, 0) * bk
 
 
 @functools.partial(jax.jit,

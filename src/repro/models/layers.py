@@ -446,7 +446,7 @@ def decode_attention(params, x, cache_k, cache_v, pos, cfg: ModelConfig,
         pos = jnp.full((B,), pos, jnp.int32)
     q, k, v = _decode_project(params, x, pos, start, cfg, rope)
     # per-slot cache write: slot b's row pos[b] (clamped in-bounds; parked
-    # slots just keep overwriting the last row, which admission re-prefills)
+    # slots sit at row 0 and overwrite it, which admission re-prefills)
     bidx = jnp.arange(B)
     pos_c = jnp.minimum(pos, S - 1)
     ck = cache_k.at[bidx, pos_c].set(k[:, 0].astype(cache_k.dtype))

@@ -33,7 +33,9 @@ Slot lifecycle (the :class:`Scheduler`)
   per-request ``max_new`` budget (clamped against ``max_seq``).  Freed
   slots keep decoding garbage (their outputs are ignored and their cache
   rows are fully overwritten by the next admission's scatter), so the
-  batch shape — and the jit signature — never changes.
+  batch shape — and the jit signature — never changes.  A freed slot is
+  parked at ``pos = start = 0`` and does not advance, so the fused flash
+  decode scans one KV tile for it.
 
 Determinism / batch invariance
 ------------------------------
@@ -306,11 +308,11 @@ padded ``bucket`` length, ``wait_ms`` from submission) holding
 (the cache write) and ``serve.first_token`` (sample and host read); a
 packed admission inside ``serve.admit_pack`` (the args summed over the
 pack, plus ``segments``).  Each decode step runs inside ``serve.step``
-(args ``step``, ``active`` slots, ``live_rows`` and ``read_rows`` of KV)
-holding ``serve.dispatch`` (the host mirrors to the device, five eager
-``jit_convert_element_type`` executions, then the decode and sample
-dispatch), ``serve.sync`` (the (B, 2) readback) and ``serve.record``
-(the bookkeeping).  No span is open across a ``yield``, and every arg
+(args ``step``, ``active`` slots, ``live_rows``, ``read_rows`` and
+``scan_rows`` of KV) holding ``serve.dispatch`` (the host mirrors to the
+device, five eager ``jit_convert_element_type`` executions, then the
+decode and sample dispatch), ``serve.sync`` (the (B, 2) readback) and
+``serve.record`` (the bookkeeping).  No span is open across a ``yield``, and every arg
 comes from host state: a span never syncs the device, and with the
 profiler off costs about a microsecond.  The spans land in the
 profiler's trace on the device ops' clock, where every engine
@@ -354,6 +356,7 @@ from jax import shard_map
 from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, PartitionSpec as PSpec
 
+from repro.kernels.posit_flash_attn import decode_rows_scanned
 from repro.models import transformer as T
 from repro.models.config import ModelConfig
 
@@ -439,7 +442,8 @@ class ServeResult:
 #: The cumulative counters of :meth:`ServeEngine.counters`; each is also a
 #: per-session ``last_serve_stats`` key.
 COUNTERS = ("decode_steps", "active_slot_steps", "admissions",
-            "prompt_tokens", "prefill_rows", "kv_rows_live", "kv_rows_read")
+            "prompt_tokens", "prefill_rows", "kv_rows_live", "kv_rows_read",
+            "kv_rows_scanned")
 
 #: Streaming events yielded by :meth:`ServeEngine.serve_stream`.
 TokenEvent = collections.namedtuple("TokenEvent", ("rid", "token"))
@@ -799,7 +803,8 @@ class _ServeState:
         self.packed_dummies = 0      # pad segments burned on pow2 rounding
         self.prefill_rows = 0        # padded prompt rows prefilled
         self.kv_rows_live = 0        # KV rows the active slots attend
-        self.kv_rows_read = 0        # KV rows decode attention scans
+        self.kv_rows_read = 0        # KV rows decode attention fetches
+        self.kv_rows_scanned = 0     # KV rows in the tiles it scans
         self.token_lats: List[float] = []
         self.closed = False          # counters folded into the engine's
 
@@ -958,6 +963,10 @@ class ServeEngine:
         if sc.kv_layout not in ("dense", "paged"):
             raise ValueError(f"kv_layout must be 'dense' or 'paged', "
                              f"got {sc.kv_layout!r}")
+        # the fused decode attention scans only each slot's live KV tiles
+        # (the hybrid family's windowed ring attends through XLA)
+        self._tile_scan = (cfg.attn_backend == "fused"
+                           and cfg.family not in ("ssm", "hybrid"))
         # recurrent families keep O(1) state — nothing to page; they fall
         # back to the dense slot path (documented in the module docstring)
         self._paged = (sc.kv_layout == "paged"
@@ -1254,8 +1263,12 @@ class ServeEngine:
           ``kv_rows_live``       KV rows the active slots attend
                                  (``pos - start + 1`` each), summed over
                                  decode steps
-          ``kv_rows_read``       KV rows decode attention scans
+          ``kv_rows_read``       KV rows decode attention fetches
                                  (``max_batch * max_seq`` a step)
+          ``kv_rows_scanned``    KV rows in the tiles decode attention
+                                 scans, over every slot (the flash
+                                 kernel's live tiles; ``kv_rows_read``
+                                 under the XLA backend)
 
         A session's own counts are its ``last_serve_stats``; the same
         increments are the args of the ``serve.admit`` and ``serve.step``
@@ -1819,14 +1832,10 @@ class ServeEngine:
         st.last_tok_ms[slot] = now
         events: List = [TokenEvent(rid, tok)]
         if st.sched.record_one(slot, tok, st.req_eos[rid]):
-            out = st.sched.evict(slot)
-            if self._paged:
-                self._release_blocks(st, slot)
-            st.temps[slot] = 0.0   # keep the all-greedy sampler fast path
             reason = (FinishReason.EOS if tok == st.req_eos[rid]
                       else FinishReason.MAX_NEW)
-            res = self._finish(st, rid, out, reason, "", now)
-            events.append(FinishEvent(rid, res))
+            events.append(FinishEvent(rid, self._evict(st, slot, rid, reason,
+                                                       "", now)))
         return events
 
     # ----------------------------------------------------- packed admission
@@ -2224,6 +2233,9 @@ class ServeEngine:
         # a parked sampled slot would otherwise disable the all-greedy
         # sampler shortcut for the rest of the stream
         st.temps[slot] = 0.0
+        # park at row 0: admission rewrites the slot's rows before it is
+        # armed again, and the decode kernel scans one tile meanwhile
+        st.pos[slot] = st.start[slot] = 0
         return self._finish(st, rid, out, reason, detail, now)
 
     # --------------------------------------------------------- the serve loop
@@ -2329,20 +2341,24 @@ class ServeEngine:
     def _step(self, st: _ServeState) -> None:
         """One decode step over all slots, its events left in
         ``st.pending``; the ``serve.step`` span (``step``, ``active``
-        slots, ``live_rows`` and ``read_rows`` of KV, all read from host
-        mirrors) and its ``serve.dispatch``, ``serve.sync`` and
-        ``serve.record`` phases."""
+        slots, ``live_rows``, ``read_rows`` and ``scan_rows`` of KV, all
+        read from host mirrors) and its ``serve.dispatch``, ``serve.sync``
+        and ``serve.record`` phases."""
         sc = self.sc
         act = st.sched.active
         n_act = int(act.sum())
         live = int((st.pos[act] - st.start[act]).sum()) + n_act
         read = sc.max_batch * sc.max_seq
+        scan = (int(decode_rows_scanned(st.pos, st.start, sc.max_seq).sum())
+                if self._tile_scan else read)
         st.decode_steps += 1
         st.active_slot_steps += n_act
         st.kv_rows_live += live
         st.kv_rows_read += read
+        st.kv_rows_scanned += scan
         with TraceAnnotation("serve.step", step=st.decode_steps,
-                             active=n_act, live_rows=live, read_rows=read):
+                             active=n_act, live_rows=live, read_rows=read,
+                             scan_rows=scan):
             if self._paged:
                 # grow each active slot's table before the row it is about
                 # to write crosses into an unmapped block
@@ -2390,7 +2406,9 @@ class ServeEngine:
         the step's events into ``st.pending``."""
         sc = self.sc
         emit = st.pending.append
-        np.minimum(st.pos + 1, sc.max_seq - 1, out=st.pos)
+        # only the slots that decoded move on: a parked slot stays at row
+        # 0, so the flash kernel scans one tile for it
+        np.minimum(st.pos + st.sched.active, sc.max_seq - 1, out=st.pos)
         st.steps += 1
         tok_h = arr[:, 0].astype(np.int32)
         healthy = arr[:, 1].astype(bool)
@@ -2544,7 +2562,8 @@ class ServeEngine:
                                    "shared_total", "peak_blocks",
                                    "packed_packs", "packed_segments",
                                    "packed_dummies", "prefill_rows",
-                                   "kv_rows_live", "kv_rows_read")},
+                                   "kv_rows_live", "kv_rows_read",
+                                   "kv_rows_scanned")},
             "token_lats": list(st.token_lats),
             "cache": jax.device_get(st.cache),
         }

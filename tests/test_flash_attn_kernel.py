@@ -138,6 +138,159 @@ def test_ste_gradients_close_to_float_reference():
                                    rtol=0, atol=5e-3)
 
 
+# ------------------------------------------------------ live-tile scan
+
+# Each case: batch, query and key lengths, tile sizes, causal, window,
+# and per-sequence kv_start / kv_len / q_pos (None: the kernel default);
+# ``bs`` switches the kernel under test to the paged layout.
+SCAN_CASES = {
+    # decode at mixed lengths: an empty slot, kv_len 1, one at max_seq
+    "decode": dict(B=5, Sq=1, Sk=64, bq=128, bk=16, causal=True, window=0,
+                   kv_start=[0, 0, 3, 20, 48], kv_len=[0, 1, 18, 41, 64],
+                   q_pos=[0, 0, 17, 40, 63]),
+    "causal_prefill": dict(B=2, Sq=64, Sk=64, bq=16, bk=16, causal=True,
+                           window=0, kv_start=[0, 21], kv_len=None,
+                           q_pos=None),
+    "window": dict(B=2, Sq=64, Sk=64, bq=16, bk=8, causal=True, window=12,
+                   kv_start=[0, 5], kv_len=None, q_pos=None),
+    "paged_decode": dict(B=4, Sq=1, Sk=64, bq=128, bk=16, causal=True,
+                         window=0, kv_start=[0, 2, 16, 40],
+                         kv_len=[1, 10, 34, 64], q_pos=[0, 9, 33, 63], bs=8),
+}
+
+
+def _scan_inputs(c, seed):
+    r = np.random.default_rng(seed)
+    q = r.normal(0, 1, (c["B"], c["Sq"], H, HD)).astype(np.float32)
+    k = r.normal(0, 1, (c["B"], c["Sk"], KV, HD)).astype(np.float32)
+    v = r.normal(0, 1, (c["B"], c["Sk"], KV, HD)).astype(np.float32)
+    ks = np.asarray(c["kv_start"] or [0] * c["B"], np.int32)
+    kl = np.asarray(c["kv_len"] or [c["Sk"]] * c["B"], np.int32)
+    qp = np.asarray(c["q_pos"] or [0] * c["B"], np.int32)
+    return q, k, v, ks, kl, qp
+
+
+def _run(c, q, k, v, ks, kl, qp, sink=0.0):
+    """The kernel under test: ``(out, m, l)``, (m, l) None when paged.
+    The paged pools give each slot its own blocks for its written rows;
+    unmapped table columns point at block 0, filled with ``sink``."""
+    from repro.kernels.posit_flash_attn import _flash_call
+
+    bt, paged = None, "bs" in c
+    if paged:
+        bs, cols = c["bs"], c["Sk"] // c["bs"]
+        used = [-(-int(n) // bs) for n in kl]
+        bt = np.zeros((c["B"], cols), np.int32)
+        pk = np.full((1 + sum(used), bs, KV, HD), sink, np.float32)
+        pv = pk.copy()
+        nxt = 1
+        for b in range(c["B"]):
+            for col in range(used[b]):
+                bt[b, col] = nxt
+                pk[nxt] = k[b, col * bs:(col + 1) * bs]
+                pv[nxt] = v[b, col * bs:(col + 1) * bs]
+                nxt += 1
+        k, v = pk, pv
+    res = _flash_call(FMT, jnp.asarray(q), jnp.asarray(k), jnp.asarray(v),
+                      c["causal"], c["window"], 0, 0.0, "srt_r4_cs_of_fr",
+                      True, c["bq"], c["bk"], 1 << 26, not paged,
+                      jnp.asarray(ks), jnp.asarray(kl), jnp.asarray(qp),
+                      None if bt is None else jnp.asarray(bt))
+    if paged:
+        return np.asarray(res), None, None
+    return tuple(np.asarray(x) for x in res)
+
+
+def _live_tiles(c, ks, kl, qp, b, i, bq):
+    """``[lo, hi)`` of the kv tiles holding a key some real query row of
+    q tile ``i`` attends, from the mask itself."""
+    rows = np.arange(i * bq, min((i + 1) * bq, c["Sq"]))
+    qpos = qp[b] + rows[:, None]
+    kpos = np.arange(c["Sk"])[None, :]
+    live = (kpos >= ks[b]) & (kpos < min(kl[b], c["Sk"]))
+    if c["causal"]:
+        live = live & (qpos >= kpos)
+    if c["window"]:
+        live = live & (qpos - kpos < c["window"])
+    tiles = np.flatnonzero(live.any(0)) // c["bk"]
+    return (tiles[0], tiles[-1] + 1) if tiles.size else (0, 0)
+
+
+def _sliced_reference(c, q, k, v, ks, kl, qp, monkeypatch):
+    """The same kernel, dense and scanning every tile it is given, with
+    every (sequence, q tile) a batch row of its own whose K/V hold
+    exactly its live tiles, positions shifted by the dropped prefix:
+    ``(out, m, l)`` in the layout of ``_run``."""
+    import repro.kernels.posit_flash_attn as FA
+
+    B, Sq, bk = c["B"], c["Sq"], c["bk"]
+    bq = min(c["bq"], -(-Sq // 8) * 8)
+    nq = -(-Sq // bq)
+    spans = [(b, i) + _live_tiles(c, ks, kl, qp, b, i, bq)
+             for b in range(B) for i in range(nq)]
+    width = max(bk, max((hi - lo) * bk for _, _, lo, hi in spans))
+    n = len(spans)
+    qr = np.zeros((n, bq, H, HD), np.float32)
+    kr = np.zeros((n, width, KV, HD), np.float32)
+    vr = np.zeros_like(kr)
+    ksr, klr, qpr = (np.zeros(n, np.int32) for _ in range(3))
+    for t, (b, i, lo, hi) in enumerate(spans):
+        rows = q[b, i * bq:(i + 1) * bq]
+        qr[t, :len(rows)] = rows
+        kr[t, :(hi - lo) * bk] = k[b, lo * bk:hi * bk]
+        vr[t, :(hi - lo) * bk] = v[b, lo * bk:hi * bk]
+        ksr[t] = ks[b] - lo * bk
+        klr[t] = (min(kl[b], c["Sk"]) - lo * bk) if hi > lo else 0
+        qpr[t] = qp[b] + i * bq - lo * bk
+    ref = dict(c, B=n, Sq=bq, Sk=width)
+    ref.pop("bs", None)
+    with monkeypatch.context() as mp:
+        mp.setattr(FA, "kv_tile_bounds",
+                   lambda *a, bk, sk_valid, **kw: (0, -(-sk_valid // bk)))
+        o, m, l = _run(ref, qr, kr, vr, ksr, klr, qpr)
+    out = np.zeros((B, nq * bq, H, HD), np.float32)
+    mm = np.zeros((B * H, nq * bq), np.float32)
+    ll = np.zeros_like(mm)
+    for t, (b, i, _, _) in enumerate(spans):
+        out[b, i * bq:(i + 1) * bq] = o[t]
+        mm[b * H:(b + 1) * H, i * bq:(i + 1) * bq] = m[t * H:(t + 1) * H]
+        ll[b * H:(b + 1) * H, i * bq:(i + 1) * bq] = l[t * H:(t + 1) * H]
+    return out[:, :Sq], mm[:, :Sq], ll[:, :Sq]
+
+
+def _same_bits(a, b):
+    np.testing.assert_array_equal(np.asarray(a).view(np.uint32),
+                                  np.asarray(b).view(np.uint32))
+
+
+@pytest.mark.parametrize("case", sorted(SCAN_CASES))
+def test_live_tile_scan_is_exact(case, monkeypatch):
+    """The kernel scans only the kv tiles a q tile can attend.  Bit for
+    bit, it equals the same kernel scanning every tile of K/V cut to
+    exactly those tiles, and rows outside ``[kv_start, kv_len)`` filled with NaN or the
+    largest float change neither the output nor the (m, l) residuals."""
+    c = SCAN_CASES[case]
+    q, k, v, ks, kl, qp = _scan_inputs(c, seed=len(case))
+    Sq = c["Sq"]
+    out, m, l = _run(c, q, k, v, ks, kl, qp)
+    ro, rm, rl = _sliced_reference(c, q, k, v, ks, kl, qp, monkeypatch)
+    _same_bits(out, ro)
+    if m is not None:
+        _same_bits(m[:, :Sq], rm)
+        _same_bits(l[:, :Sq], rl)
+    dead = ((np.arange(c["Sk"])[None, :] < ks[:, None])
+            | (np.arange(c["Sk"])[None, :] >= kl[:, None]))
+    assert dead.any()
+    for fill in (np.nan, np.finfo(np.float32).max):
+        kd, vd = k.copy(), v.copy()
+        kd[dead], vd[dead] = fill, fill
+        o2, m2, l2 = _run(c, q, kd, vd, ks, kl, qp, sink=fill)
+        _same_bits(o2, out)
+        if m is not None:
+            _same_bits(m2, m)
+            _same_bits(l2, l)
+
+
 # ------------------------------------------------------ fused backward
 
 

@@ -126,9 +126,13 @@ def test_spans_args_nesting_and_counters(engine, tmp_path):
     # the span args are the counters' increments
     live = sum(s[3]["live_rows"] for s in steps)
     read = sum(s[3]["read_rows"] for s in steps)
+    scan = sum(s[3]["scan_rows"] for s in steps)
     assert live == c1["kv_rows_live"] - c0["kv_rows_live"]
     assert read == c1["kv_rows_read"] - c0["kv_rows_read"]
+    assert scan == c1["kv_rows_scanned"] - c0["kv_rows_scanned"]
     assert read == len(steps) * B * MAX_SEQ
+    # XLA decode attention scans every row it reads
+    assert scan == read
     assert sum(s[3]["active"] for s in steps) == \
         c1["active_slot_steps"] - c0["active_slot_steps"]
     # a request of prompt n decodes its tokens 2..m at n + 1 .. n + m - 1
@@ -164,6 +168,55 @@ def test_counters_sum_the_sessions(engine):
     # warm-up sessions are not counted
     engine.warmup(prompt_lens=[8])
     assert engine.counters() == c2
+
+
+FUSED_SEQ, TILE = 384, 128   # the decode kernel's kv tile at this max_seq
+# a 150-token prompt lands in bucket 256 (start 106) and decodes over
+# three tiles; the others take one, and each slot parks in between
+FUSED_STREAM = [(np.arange(1, 151, dtype=np.int32) % 97 + 1, 3),
+                (np.arange(2, 7, dtype=np.int32), 2),
+                (np.arange(3, 6, dtype=np.int32), 3)]
+
+
+@pytest.mark.parametrize("layout", ["dense", "paged"])
+def test_parked_slots_and_rows_scanned(layout, tmp_path, monkeypatch):
+    """Under the fused flash decode a freed slot parks at ``pos == start
+    == 0`` and stays there, and ``kv_rows_scanned`` counts the rows of
+    the tiles the kernel scans: each slot's tiles from ``start`` to
+    ``pos``, recounted here from the mirrors each step ships."""
+    cfg = get_config("smollm-360m", smoke=True, fused=True)
+    params = T.init_params(cfg, jax.random.PRNGKey(0))
+    eng = ServeEngine(cfg, params, ServeConfig(
+        max_batch=B, max_seq=FUSED_SEQ, kv_layout=layout))
+    reqs = [Request(p, max_new=m) for p, m in FUSED_STREAM]
+
+    shipped, parked = [], []
+    step = eng._step
+
+    def watched(st):
+        shipped.append((st.pos.copy(), st.start.copy()))
+        step(st)
+        idle = ~st.sched.active
+        parked.append(int(idle.sum()))
+        assert (st.pos[idle] == 0).all() and (st.start[idle] == 0).all()
+
+    monkeypatch.setattr(eng, "_step", watched)
+    c0 = eng.counters()
+    outs, spans = _traced(tmp_path, lambda: eng.serve(reqs))
+    c1 = eng.counters()
+    assert sum(parked) > 0, "no slot was ever parked"
+    assert [len(o) for o in outs] == [m for _, m in FUSED_STREAM]
+
+    steps = [s for s in spans if s[0] == "serve.step"]
+    assert len(steps) == len(shipped)
+    recount = sum(int(((pos // TILE) - (start // TILE) + 1).sum()) * TILE
+                  for pos, start in shipped)
+    scan = sum(s[3]["scan_rows"] for s in steps)
+    assert scan == recount == c1["kv_rows_scanned"] - c0["kv_rows_scanned"]
+    assert eng.last_serve_stats["kv_rows_scanned"] == scan
+    assert scan < c1["kv_rows_read"] - c0["kv_rows_read"]
+    # the long request scans three tiles a step
+    assert max(s[3]["scan_rows"] for s in steps) >= 3 * TILE
 
 
 def test_ttft_stats_follow_the_results(engine):

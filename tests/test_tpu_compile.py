@@ -110,6 +110,13 @@ CASES = {
             seg_kv=skv),
         [((1, 512, H, HD), F32), ((1, 512, KV, HD), F32),
          ((1, 512, KV, HD), F32), ((1, 512), I32), ((1, 512), I32)], 1),
+    # sliding-window attention (the hybrid family's local layers): the
+    # scan's lower tile bound follows the window
+    "flash_prefill_window": (
+        lambda q, k, v: posit_flash_attention(
+            P16, q, k, v, True, 128, 0, 0.0, VARIANT, False),
+        [((1, 512, H, HD), F32), ((1, 512, KV, HD), F32),
+         ((1, 512, KV, HD), F32)], 1),
     "flash_forward_residuals": (
         lambda q, k, v: posit_flash_attention_fwd(
             P16, q, k, v, True, 0, 0, 0.0, VARIANT, False),
