@@ -1,9 +1,10 @@
 """Model step, decode (the engine's decode executable): the FLOPs the
-traced span's decode tokens need (active slots, live KV rows) over the
-decode executions' device time times the chip's bf16 peak, in %."""
+traced span's decode tokens need, as the cell's block counts them
+(active slots, live KV rows), over the decode executions' device time
+times the chip's bf16 peak, in %."""
 
+import blocks
 import trace_reduce as TRC
-import work
 
 
 def read(rec):
@@ -12,5 +13,5 @@ def read(rec):
     if not st or not st["decode"] or not rows:
         return None
     t = TRC.seconds_of(rec["trace"]["reduced"], st["decode"])
-    return 100.0 * work.decode_flops(rec["model"], rows) / (
+    return 100.0 * blocks.get(rec["block"]).decode_flops(rec["model"], rows) / (
         t * rec["peaks"]["bf16_flops_per_s"])

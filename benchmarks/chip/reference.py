@@ -1,12 +1,11 @@
-"""Plain float32 reference of the dense decoder, and its low-precision control.
+"""The plain float32 reference's readings of served tokens, and its
+low-precision control.
 
-A copy, kept with the benchmark, of the textbook llama block: RMSNorm,
-half-split RoPE, causal GQA softmax attention and a SwiGLU MLP, in
-``jax.numpy`` and float32 under ``default_matmul_precision("highest")``,
-with no posit numerics, kernels, cache or batching.  The layers run under
-``lax.scan`` so that one program serves every depth.  It reads the sizes
-from the benchmark's configuration file and the weights the benchmark made
-(``weights.py``), and imports nothing of the program.
+The reference is the configuration's block (``blocks/<name>.py``
+``logits``): the architecture in ``jax.numpy`` and float32 under
+``default_matmul_precision("highest")``, with no posit numerics, kernels,
+cache or batching, on the weights the benchmark made, importing nothing
+of the program.
 
 ``precision="fp8"`` is the control: every weight and every activation that
 enters a linear layer is rounded to float8 e4m3 with one scale per tensor,
@@ -21,6 +20,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+import blocks
+
 F8_MAX = 448.0
 
 
@@ -29,71 +30,27 @@ def _fp8(x):
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
 
 
-def _rmsnorm(x, w, eps):
-    return x * jax.lax.rsqrt(jnp.mean(x * x, -1, keepdims=True) + eps) * (1.0 + w)
-
-
-def _rope(x, theta):
-    S, hd = x.shape[0], x.shape[-1]
-    freqs = 1.0 / theta ** (jnp.arange(0, hd, 2, dtype=jnp.float32) / hd)
-    ang = jnp.arange(S, dtype=jnp.float32)[:, None, None] * freqs
-    x1, x2 = x[..., :hd // 2], x[..., hd // 2:]
-    c, s = jnp.cos(ang), jnp.sin(ang)
-    return jnp.concatenate([x1 * c - x2 * s, x2 * c + x1 * s], axis=-1)
-
-
-def logits(params, model: dict, tokens, precision: str = "f32"):
-    """(S,) int32 tokens -> (S, vocab) float32 logits."""
-    q8 = _fp8 if precision == "fp8" else (lambda a: a)
-    H, KV = model["n_heads"], model["n_kv_heads"]
-    hd, eps, theta = model["head_dim"], model["norm_eps"], model["rope_theta"]
-    S = tokens.shape[0]
-    causal = jnp.tril(jnp.ones((S, S), bool))
-    x = params["embed"]["tok"][tokens]
-
-    def layer(x, p):
-        h = q8(_rmsnorm(x, p["ln1"], eps))
-        a = p["attn"]
-        q = _rope(jnp.einsum("sd,dhk->shk", h, q8(a["wq"])), theta)
-        k = _rope(jnp.einsum("sd,dhk->shk", h, q8(a["wk"])), theta)
-        v = jnp.einsum("sd,dhk->shk", h, q8(a["wv"]))
-        k = jnp.repeat(k, H // KV, axis=1)
-        v = jnp.repeat(v, H // KV, axis=1)
-        s = jnp.einsum("qhk,shk->hqs", q, k) / jnp.sqrt(jnp.float32(hd))
-        s = jnp.where(causal, s, -jnp.inf)
-        o = jnp.einsum("hqs,shk->qhk", jax.nn.softmax(s, axis=-1), v)
-        x = x + jnp.einsum("shk,hkd->sd", q8(o), q8(a["wo"]))
-        h = q8(_rmsnorm(x, p["ln2"], eps))
-        m = p["mlp"]
-        g = jax.nn.silu(h @ q8(m["w1"])) * (h @ q8(m["w3"]))
-        return x + q8(g) @ q8(m["w2"]), None
-
-    x, _ = jax.lax.scan(layer, x, params["blocks"])
-    x = q8(_rmsnorm(x, params["ln_f"], eps))
-    e = params["embed"]
-    head = e["tok"].T if model["tie_embeddings"] else e["head"]
-    return (x @ q8(head))[:, :model["vocab"]]
-
-
-@functools.partial(jax.jit, static_argnames=("model_items", "precision"))
-def _readings(params, tokens, targets, *, model_items, precision):
+@functools.partial(jax.jit,
+                   static_argnames=("block", "model_items", "precision"))
+def _readings(params, tokens, targets, *, block, model_items, precision):
     """Per position: the reference's best logit, its logit of ``targets``,
     and (``precision != "f32"``) its logit of the token the control puts
     first."""
     model = dict(model_items)
+    logits = blocks.get(block).logits
     with jax.default_matmul_precision("highest"):
-        ref = logits(params, model, tokens)
+        ref = logits(params, model, tokens, lambda a: a)
     best = jnp.max(ref, axis=-1)
     at = jnp.take_along_axis(ref, targets[:, None], axis=-1)[:, 0]
     if precision == "f32":
         return best, at, at
     with jax.default_matmul_precision("default"):
-        low = logits(params, model, tokens, precision)
+        low = logits(params, model, tokens, _fp8)
     first = jnp.argmax(low, axis=-1)
     return best, at, jnp.take_along_axis(ref, first[:, None], axis=-1)[:, 0]
 
 
-def widest_gaps(params, model: dict, prompt, served, length: int,
+def widest_gaps(conf: dict, params, prompt, served, length: int,
                 control: str = ""):
     """Widest gap by which a served token's reference logit lies below the
     reference's best, over every served position of one request, and with
@@ -111,7 +68,8 @@ def widest_gaps(params, model: dict, prompt, served, length: int,
     tgt = np.zeros(length, np.int32)
     tgt[n - 1:n - 1 + m] = served
     best, at, low = _readings(params, jnp.asarray(toks), jnp.asarray(tgt),
-                              model_items=tuple(sorted(model.items())),
+                              block=conf["block"],
+                              model_items=tuple(sorted(conf["model"].items())),
                               precision=control or "f32")
     sl = slice(n - 1, n - 1 + m)
     best, at, low = (np.asarray(v, np.float64)[sl] for v in (best, at, low))

@@ -7,12 +7,14 @@ traffic mix) per run.
 
 The cell, its configuration file and its traffic mix are looked up by name
 in ``BENCHMARK.json``; per-layer metrics are the readers under
-``metrics/``.  A run makes its weights and requests from ``--seed``, warms
-up every prompt bucket the mix uses, runs the mix's lead-in, measures for
-``--seconds``, and then checks what the window served against the plain
-float32 reference.  The last line of standard output is one JSON object;
-with ``--trace 1`` the metrics are the per-layer ones, read from a
-profiler trace of part of the window.  Without a TPU, or with fewer chips
+``metrics/``.  The configuration file names its block (``blocks/<name>.py``:
+the architecture's weights, reference, work counts and division sites).
+A run makes its weights and requests from ``--seed``, warms up every
+prompt bucket the mix uses, runs the mix's lead-in, measures for
+``--seconds``, and then checks what the window served against the block's
+plain float32 reference.  The last line of standard output is one JSON
+object; with ``--trace 1`` the metrics are the per-layer ones, read from
+a profiler trace of part of the window.  Without a TPU, or with fewer chips
 than the cell asks for, it exits nonzero and prints no result.
 """
 
@@ -141,21 +143,14 @@ class CompileCounter:
 
 def build_engine(conf: dict, params):
     """The program's serving engine for a configuration file."""
-    import jax
-
+    import weights
     from repro.configs import get_config
-    from repro.models import transformer as T
     from repro.serve import ServeConfig, ServeEngine
 
     cfg = get_config(conf["arch"], fused=conf["fused"],
                      max_batch=conf["slots"], max_seq=conf["max_seq"])
     cfg = cfg.replace(**conf["model"])
-    want = jax.tree.map(lambda a: (a.shape, a.dtype), jax.eval_shape(
-        lambda: T.init_params(cfg, jax.random.PRNGKey(0))))
-    have = jax.tree.map(lambda a: (a.shape, a.dtype), params)
-    if want != have:
-        raise SystemExit("the benchmark's weight tree does not match the "
-                         "program's parameter layout")
+    weights.check(conf, params, cfg)
     sc = ServeConfig.from_model(cfg, temperature=0.0, eos_id=-1)
     return ServeEngine(cfg, params, sc)
 
@@ -377,13 +372,14 @@ def check(params, conf: dict, cfg, sample: List[Rec], div_req: Optional[Rec],
     """The numbers ``correct`` compares, each with its limit.  With
     ``control`` the low-precision reference takes the program's place:
     ``logit_gap`` is then the gap of the tokens the control puts first."""
+    import blocks
     import divsites
     import reference
 
     length = conf["check_length"]
     gaps, lows = [], []
     for r in sample:
-        g, lo = reference.widest_gaps(params, conf["model"], r.prompt,
+        g, lo = reference.widest_gaps(conf, params, r.prompt,
                                       np.asarray(r.tokens), length, control)
         gaps.append(g)
         lows.append(lo)
@@ -393,17 +389,18 @@ def check(params, conf: dict, cfg, sample: List[Rec], div_req: Optional[Rec],
         P = TR.bucket(n)
         toks = np.zeros(P, np.int32)
         toks[P - n:] = div_req.prompt
-        norms, flash = divsites.capture(cfg, params, toks, P - n,
-                                        conf["max_seq"])
-        div = divsites.compare(norms, flash, conf["posit_bits"])
+        sites = blocks.get(conf["block"]).DIVISION_SITES
+        seen = divsites.capture(cfg, params, toks, P - n, conf["max_seq"],
+                                sites)
+        div = divsites.compare(sites, seen, conf["posit_bits"])
     served = lows if control else gaps
     limits = conf["limits"]
     out = {
         "logit_gap": {"value": max(served) if served else None,
                       "limit": limits["logit_gap"]},
-        "divider_mismatches": {"value": div.get("norm_mismatches"),
+        "divider_mismatches": {"value": div.get("quotient_mismatches"),
                                "limit": limits["divider_mismatches"]},
-        "flash_off_grid": {"value": div.get("flash_off_grid"),
+        "flash_off_grid": {"value": div.get("grid_off"),
                            "limit": limits["flash_off_grid"]},
     }
     out["_detail"] = {"requests": len(sample), "control": control or None,
@@ -487,9 +484,10 @@ def prepare(cell: dict, seed: int) -> dict:
     seed, the engine, and one warm-up request per prompt bucket."""
     import jax
 
-    import weights
+    import blocks
 
     conf, mix = cell["config"], cell["mix"]
+    block = blocks.get(conf.get("block"))
     devs = devices(cell["chips"])
     dev = devs[0]
     cache_dir = enable_compile_cache()
@@ -497,7 +495,7 @@ def prepare(cell: dict, seed: int) -> dict:
     log(f"[setup] {dev.platform} {dev.device_kind} x{len(devs)}; compile cache "
         f"{cache_dir}")
     log(f"[setup] jax up: {time.perf_counter() - T_START:.3f} s since start")
-    params = weights.make(conf["model"], seed)
+    params = block.make_weights(conf["model"], seed)
     jax.block_until_ready(params)
     log(f"[setup] weights: {time.perf_counter() - T_START:.3f} s since start")
     eng = build_engine(conf, params)
@@ -553,7 +551,8 @@ def run(cell: dict, seed: int, seconds: float, traced: bool,
     checks = check(params, conf, cfg, sample, divider_request(client, seed),
                    control)
     rec = {
-        "model": conf["model"], "slots": conf["slots"], "peaks": peaks,
+        "block": conf["block"], "model": conf["model"],
+        "slots": conf["slots"], "peaks": peaks,
         "loop": mix["loop"], "requests": client.recs,
         "window": (client.t0, client.t1),
         "counters": {k: counts1[k] - client.counts0[k] for k in counts1},

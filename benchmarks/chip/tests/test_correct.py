@@ -58,10 +58,54 @@ def test_sound_run_is_correct(cell):
     assert out["correct"], out["checks"]
     assert out["compiles_in_window"] == 0
     d = out["checks"]["_detail"]
-    assert d["norm_quotients"] > 0 and d["flash_outputs"] > 0, d
+    assert d["quotients"] > 0 and d["grid_outputs"] > 0, d
     # every layer's two RMSNorms and the final one; every layer's attention
     layers = cell["config"]["model"]["n_layers"]
-    assert (d["norm_sites"], d["flash_sites"]) == (2 * layers + 1, layers), d
+    assert (d["quotient_sites"], d["grid_sites"]) == (2 * layers + 1, layers), d
+    by_site = {k: v["calls"] for k, v in d["by_site"].items()}
+    assert by_site == {"posit_rmsnorm_div": 2 * layers + 1,
+                       "posit_flash_attention": layers}, d
+
+
+@pytest.fixture
+def recording_block(monkeypatch):
+    """``tests/blocks/recording.py`` found as a block beside the others."""
+    import sys
+
+    import blocks
+
+    monkeypatch.setattr(blocks, "__path__",
+                        [*blocks.__path__, str(HERE / "blocks")])
+    yield
+    mod = sys.modules.pop("blocks.recording", None)
+    if mod is not None:
+        delattr(blocks, "recording")
+
+
+def test_block_added_as_a_file_is_reached(cell, recording_block):
+    """A configuration that names another block reaches it, by its file
+    alone, through the weights, the reference, the division sites and the
+    per-layer metrics' counts."""
+    import blocks
+    import trace_reduce as TRC
+
+    conf = dict(cell["config"], block="recording")
+    out = R.run(dict(cell, config=conf), 11, 3.0, traced=False)
+    assert out["correct"], out["checks"]
+    assert out["rec"]["block"] == "recording"
+    calls = blocks.get("recording").CALLS
+    assert {"make_weights", "logits", "DIVISION_SITES"} <= calls, calls
+    # the readers of a recorded v5e trace, for a run of that block
+    red = TRC.Reduced(TRC.load(str(HERE / "v5e_chat_spans.json.gz")))
+    steps = sum(1 for e in red.host if e[2] == "serve.step")
+    rec = dict(out["rec"], trace={"reduced": red, "decode_steps": steps,
+                                  "decode_live_rows": [300, 500],
+                                  "prefill_prompts": [180]})
+    for name in ("mfu.decode", "mfu.prefill", "flash_decode_roofline",
+                 "srt_div_prefill_roofline"):
+        assert R.read_metric(name, rec) is not None, name
+    assert {"decode_flops", "prefill_flops", "flash_decode_bytes",
+            "rmsnorm_divide_bytes"} <= calls, calls
 
 
 def test_decode_step_that_returns_its_cache_unchanged(cell, monkeypatch):

@@ -23,7 +23,8 @@ def _host(name, start, dur, **args):
 
 
 def _rec(events, **trace):
-    return {"model": SMOLLM["model"], "peaks": R.peaks_for("TPU v5 lite"),
+    return {"block": SMOLLM["block"], "model": SMOLLM["model"],
+            "peaks": R.peaks_for("TPU v5 lite"),
             "trace": dict(reduced=TRC.Reduced(events), **trace)}
 
 
